@@ -47,6 +47,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
 
 from bench_schema import stage_breakdown, write_bench
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.frontend import (
     AsyncFrontendClient,
     Gateway,
@@ -275,7 +276,7 @@ def main(argv=None):
     )
     cfg = GSConfig(img_h=args.res, img_w=args.res, k_per_tile=128 if args.smoke else 256)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh((n_dev, 1))
 
     manager = SessionManager(
         cfg, mesh=mesh, n_levels=args.levels, max_batch=args.max_batch,

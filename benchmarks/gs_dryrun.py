@@ -41,15 +41,16 @@ def main():
     from repro.core import gaussians as G
     from repro.core import projection as P
     from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
     from repro.core.train import init_state, make_train_step
     from repro.launch import hlo_cost
     from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 
     if args.pods > 1:
-        mesh = jax.make_mesh((args.pods, args.data_par, args.workers), ("pod", "data", "model"))
+        mesh = make_mesh((args.pods, args.data_par, args.workers), ("pod", "data", "model"))
         data_axes = ("pod", "data")
     else:
-        mesh = jax.make_mesh((args.data_par, args.workers), ("data", "model"))
+        mesh = make_mesh((args.data_par, args.workers))
         data_axes = ("data",)
     quantum = args.workers * 256
     n = int(np.ceil(args.points / quantum) * quantum)

@@ -30,12 +30,11 @@ import os
 import sys
 import tempfile
 
-import jax
-
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 
 from bench_schema import stage_breakdown, write_bench
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.insitu import InsituTrainer, TemporalCheckpointStore, build_timeline_server, scrub
 from repro.serve_gs import front_camera
 from repro.volume.timevary import GENERATORS, synthetic_stream
@@ -97,7 +96,7 @@ def main(argv=None):
         args.cold_steps = min(args.cold_steps, 80)
         args.t1 = min(args.t1, 0.15)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(
         img_h=args.res, img_w=args.res, batch_size=args.batch,
         k_per_tile=128 if args.smoke else 256,

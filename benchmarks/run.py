@@ -27,12 +27,13 @@ def main() -> None:
     print("\n# --- GS train step (single device, reduced scale) ---")
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
     from repro.core.train import init_state, make_train_step, state_shardings
     from repro.core import gaussians as G
     from repro.volume import kingsnake_like, extract_isosurface_points, orbit_cameras, render_isosurface
     from repro.volume.cameras import camera_slice
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(img_h=64, img_w=64, k_per_tile=192, batch_size=2, backend="ref")
     vol = kingsnake_like(res=32)
     pts, _, cols = extract_isosurface_points(vol, max_points=1500, seed=0)

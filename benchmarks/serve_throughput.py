@@ -41,6 +41,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
 
 from bench_schema import stage_breakdown, write_bench
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.launch.serve_gs import init_params_from_volume
 from repro.serve_gs import RenderServer, make_clients, run_load
 from repro.serve_gs.batcher import stack_cameras
@@ -153,8 +154,8 @@ def main(argv=None):
     )
 
     n_dev = len(jax.devices())
-    mesh_serial = jax.make_mesh((1, 1), ("data", "model"))
-    mesh_batched = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh_serial = make_mesh((1, 1))
+    mesh_batched = make_mesh((n_dev, 1))
 
     # ---- serial baseline: one request per dispatch, single device, no cache
     serial = build_server(params, cfg, mesh=mesh_serial, max_batch=1, cache_capacity=0, **common)

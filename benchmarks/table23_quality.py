@@ -22,6 +22,7 @@ SCRIPT = textwrap.dedent(
         os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nd}"
     import jax, numpy as np, jax.numpy as jnp
     from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
     from repro.core.train import init_state, make_train_step, make_eval_render, state_shardings
     from repro.core import gaussians as G
     from repro.core.losses import psnr, ssim, lpips_proxy
@@ -29,7 +30,7 @@ SCRIPT = textwrap.dedent(
     from repro.data.views import ViewDataset
 
     shape = {1: (1,1), 2: (2,1), 4: (2,2), 8: (4,2)}[nd]
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape)
     H = 64
     cfg = GSConfig(img_h=H, img_w=H, k_per_tile=192, batch_size=4, backend="ref")
     vol = kingsnake_like(res=40)

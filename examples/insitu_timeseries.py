@@ -13,9 +13,8 @@ and a time-scrubbing render across the stored sequence.
 import os
 import tempfile
 
-import jax
-
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.insitu import InsituTrainer, TemporalCheckpointStore, build_timeline_server, scrub
 from repro.serve_gs import front_camera
 from repro.volume.timevary import synthetic_stream
@@ -23,7 +22,7 @@ from repro.volume.timevary import synthetic_stream
 
 def main():
     H = 48
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(
         img_h=H, img_w=H, batch_size=2, k_per_tile=128, max_steps=200,
         densify_from=10**9, opacity_reset_interval=10**9,

@@ -9,6 +9,7 @@ import numpy as np
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
 from repro.core.losses import psnr
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state, make_eval_render, make_train_step, state_shardings
 from repro.data.views import ViewDataset
 from repro.volume import extract_isosurface_points, kingsnake_like
@@ -29,7 +30,7 @@ g = G.init_from_points(jnp.asarray(points), jnp.asarray(colors), init_scale=0.05
 
 # 4. distributed-ready train step (here on a trivial 1x1 mesh — the same code
 #    runs Gaussian-sharded + pixel-sharded on a real TPU mesh)
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = make_mesh((1, 1))
 cfg = GSConfig(img_h=64, img_w=64, batch_size=4, k_per_tile=192)
 state = jax.device_put(init_state(g), state_shardings(mesh))
 step = make_train_step(mesh, cfg)

@@ -14,6 +14,7 @@ import numpy as np
 from repro.checkpoint import latest_step, restore_checkpoint
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state, make_eval_render, state_shardings
 from repro.utils.image import write_ppm
 from repro.volume.cameras import camera_slice, orbit_cameras
@@ -37,7 +38,7 @@ def main():
     like = init_state(G.init_from_points(jnp.zeros((n, 3)), jnp.zeros((n, 3))))
     state = restore_checkpoint(args.ckpt, step, jax.tree_util.tree_map(np.asarray, like))
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(img_h=args.res, img_w=args.res, k_per_tile=256)
     render = make_eval_render(mesh, cfg)
     params = G.GaussianModel(*[jnp.asarray(x) for x in state.params])

@@ -19,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from repro.core import gaussians as G
 from repro.core import projection as P
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state, make_train_step, state_shardings, make_eval_render
 from repro.volume import kingsnake_like, extract_isosurface_points, orbit_cameras, render_isosurface
 from repro.volume.cameras import camera_slice
@@ -27,7 +28,7 @@ from repro.core.losses import psnr
 devs = jax.devices()
 nd = len(devs)
 dshape = {1: (1, 1), 2: (2, 1), 4: (2, 2), 8: (4, 2)}[nd]
-mesh = jax.make_mesh(dshape, ("data", "model"))
+mesh = make_mesh(dshape)
 print("mesh", mesh.shape)
 
 H = W = 64

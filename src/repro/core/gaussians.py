@@ -48,6 +48,15 @@ def num_params(g: GaussianModel) -> int:
     return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(g))
 
 
+def default_init_scale(points: jax.Array) -> jax.Array:
+    """Mean nearest-neighbor spacing estimated from the bounding-box density."""
+    points = jnp.asarray(points, jnp.float32)
+    lo = jnp.min(points, axis=0)
+    hi = jnp.max(points, axis=0)
+    vol = jnp.prod(jnp.maximum(hi - lo, 1e-6))
+    return jnp.clip((vol / jnp.maximum(points.shape[0], 1)) ** (1.0 / 3.0), 1e-4, 1e2)
+
+
 def init_from_points(
     points: jax.Array,
     colors: jax.Array | None = None,
@@ -73,10 +82,7 @@ def init_from_points(
     sh = sh.at[:, 0, :].set((jnp.asarray(colors, jnp.float32) - 0.5) / SH_C0)
 
     if init_scale is None:
-        lo = jnp.min(points, axis=0)
-        hi = jnp.max(points, axis=0)
-        vol = jnp.prod(jnp.maximum(hi - lo, 1e-6))
-        init_scale = jnp.clip((vol / jnp.maximum(n, 1)) ** (1.0 / 3.0), 1e-4, 1e2)
+        init_scale = default_init_scale(points)
     log_scales = jnp.broadcast_to(jnp.log(jnp.asarray(init_scale, jnp.float32)), (n, 3)).astype(jnp.float32)
 
     quats = jnp.zeros((n, 4), jnp.float32).at[:, 0].set(1.0)
@@ -102,12 +108,20 @@ def quat_to_rotmat(quats: jax.Array) -> jax.Array:
     )
 
 
+def small_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` for stacks of tiny matrices (2x3, 3x3), as exact f32
+    multiply-adds. As a dot, XLA on TPU runs them on the MXU in one bf16 pass
+    and pads every 3x3 block to a full tile (GiBs of temporaries at 500k
+    Gaussians)."""
+    return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
+
+
 def covariance3d(g: GaussianModel) -> jax.Array:
     """(N,3,3) world-space covariance R S S^T R^T."""
     R = quat_to_rotmat(g.quats)
     s = scales(g)
     RS = R * s[:, None, :]
-    return RS @ jnp.swapaxes(RS, -1, -2)
+    return small_matmul(RS, jnp.swapaxes(RS, -1, -2))
 
 
 def eval_sh(sh: jax.Array, dirs: jax.Array) -> jax.Array:

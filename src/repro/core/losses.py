@@ -33,7 +33,7 @@ def ssim(img0: jax.Array, img1: jax.Array, *, window_size: int = 11) -> jax.Arra
         # (H,W,C) -> depthwise conv
         x = jnp.moveaxis(x, -1, 0)[:, None]  # (C,1,H,W)
         k = jnp.broadcast_to(jnp.moveaxis(win, (0, 1), (2, 3)), (1, 1, window_size, window_size))
-        y = jax.lax.conv_general_dilated(x, k, (1, 1), "SAME")
+        y = jax.lax.conv_general_dilated(x, k, (1, 1), "SAME", precision=jax.lax.Precision.HIGHEST)
         return jnp.moveaxis(y[:, 0], 0, -1)
 
     mu0, mu1 = filt(img0), filt(img1)

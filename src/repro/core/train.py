@@ -22,7 +22,7 @@ from repro.core import gaussians as G
 from repro.core import projection as P
 from repro.core import render as R
 from repro.core.config import GSConfig
-from repro.core.sharding import distributed_gs_loss, shard_map
+from repro.core.sharding import distributed_gs_loss
 from repro.optim.adam import AdamState, adam_init, adam_update
 from repro.optim.schedules import expon_lr, grendel_lr_scale
 from repro.utils.tree import pack_pytree
@@ -324,7 +324,7 @@ def make_train_step(
     cam_spec = P.Camera(*([PS(data_axes)] * 5))
     gt_spec = PS(data_axes, model_axis) if strip else PS(data_axes)
 
-    stepped = shard_map(
+    stepped = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(st_specs, cam_spec, gt_spec),
@@ -362,7 +362,7 @@ def make_eval_render(mesh: Mesh, cfg: GSConfig, *, model_axis: str = "model"):
         )
         return img, t
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(G.GaussianModel(*([PS(model_axis)] * 5)), P.Camera(*([PS()] * 5))),
@@ -414,7 +414,7 @@ def make_tile_row_render(mesh: Mesh, cfg: GSConfig, *, row: int, model_axis: str
         )
         return img
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(G.GaussianModel(*([PS(model_axis)] * 5)), P.Camera(*([PS()] * 5))),
@@ -478,7 +478,7 @@ def make_batched_eval_render(
             return jax.lax.map(one, cams)
         return jax.vmap(one)(cams)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

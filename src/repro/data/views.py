@@ -39,7 +39,13 @@ class ViewDataset:
         cache_file = None
         if cache_dir:
             os.makedirs(cache_dir, exist_ok=True)
-            cache_file = os.path.join(cache_dir, f"{vol.name}_{n_views}v_{img_h}x{img_w}.npy")
+            # every setting that shapes the images is in the name, so a file
+            # made under other settings is never read back
+            key = (
+                f"{vol.name}_r{vol.field.shape[0]}_e{vol.extent:g}_{n_views}v_{img_h}x{img_w}"
+                f"_rad{radius:g}_s{n_steps_raymarch}"
+            )
+            cache_file = os.path.join(cache_dir, key + ".npy")
         if cache_file and os.path.exists(cache_file):
             self.gt = np.load(cache_file)
         else:

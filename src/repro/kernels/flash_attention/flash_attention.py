@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 BQ = 128
 
 
@@ -53,7 +55,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal, window, q_offset, scale):
 def make_flash(bh: int, sq: int, skv: int, hd: int, causal: bool, window, q_offset: int,
                dtype_name: str, interpret=None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     scale = 1.0 / (hd ** 0.5)
     kern = functools.partial(_kernel, causal=causal, window=window, q_offset=q_offset, scale=scale)
     dtype = jnp.dtype(dtype_name)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 BLOCK_N = 1024
 CAM_SLOTS = 32  # viewmat(16), fx, fy, cx, cy, near, campos(3) -> padded to 32
 
@@ -98,7 +100,7 @@ def _kernel(means_ref, scales_ref, quats_ref, opac_ref, sh0_ref, cam_ref, out_re
 @functools.lru_cache(maxsize=None)
 def make_project(n_padded: int, blur: float = 0.3, interpret=None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     kern = functools.partial(_kernel, blur=blur)
     grid = (n_padded // BLOCK_N,)
 

@@ -16,8 +16,15 @@ _CAM_USED = 16 + 5 + 3  # viewmat(4x4 row-major), fx/fy/cx/cy/near, campos
 
 def project_packed(g: G.GaussianModel, cam: P.Camera, *, backend: str = "ref", near: float = 0.01):
     """(N, 11) packed splats. backend="pallas" requires sh_degree == 0."""
-    if backend == "ref" or g.sh.shape[1] != 1:
+    if backend == "ref":
         return project_ref(g, cam, near=near)
+    if backend != "pallas":
+        raise ValueError(f"unknown backend {backend!r}")
+    if g.sh.shape[1] != 1:
+        raise ValueError(
+            f"the Pallas projection kernel evaluates degree-0 SH only; this model "
+            f"has sh_degree {g.sh_degree} — use backend='ref'"
+        )
 
     @jax.custom_vjp
     def fwd(gm):

@@ -53,7 +53,8 @@ def compose_tile(
     # transmittance after the last composited splat (1.0 if none composited;
     # t_incl is non-increasing so the min over alive entries is the last one)
     t_final = jnp.min(jnp.where(alive, t_incl, 1.0), axis=0)
-    out = jnp.einsum("kp,kc->pc", w, rgb) + t_final[:, None] * bg[None, :]
+    out = jnp.einsum("kp,kc->pc", w, rgb, precision=jax.lax.Precision.HIGHEST)
+    out = out + t_final[:, None] * bg[None, :]
     return out, t_final
 
 

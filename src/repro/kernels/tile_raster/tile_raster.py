@@ -19,7 +19,10 @@ nothing but the inputs and the output cotangents are needed) and emits
 per-splat parameter gradients with two more MXU matmuls plus a reverse scan.
 
 Block sizes: one grid step = one image tile. VMEM footprint ~ a few (K,P)
-f32 temporaries: K=1024, P=256 -> 1 MB each, well inside 16 MB VMEM.
+f32 temporaries: K=1024, P=256 -> 1 MB each. The matmuls run at HIGHEST
+precision (Mosaic's default for f32 is bf16 passes); at K=1024 the backward
+then needs ~17 MB of scoped VMEM, over Mosaic's 16 MB default, so the
+kernels ask for 32 MB (a v5e core has 128 MB).
 """
 from __future__ import annotations
 
@@ -28,10 +31,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.tile_raster.ref import ALPHA_MAX, ALPHA_MIN, T_EPS
 
 _NEG_BIG = -1e30
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _inclusive_cumsum_doubling(x: jax.Array) -> jax.Array:
@@ -102,16 +108,17 @@ def _alpha_and_trans(splats, valid, px, py):
 def _fwd_kernel(splats_ref, valid_ref, out_ref, tfin_ref, *, tiles_x, tile_h, tile_w, row_offset):
     t = pl.program_id(0)
     splats = splats_ref[0]  # (11,K)
-    valid = valid_ref[...]  # (1,K)
+    valid = valid_ref[0]    # (1,K)
     px, py = _pixel_coords(t, tiles_x, tile_h, tile_w, row_offset)
     alpha, _, _, t_incl, t_excl, alive, colors, _ = _alpha_and_trans(splats, valid, px, py)
     w = jnp.where(alive, alpha * t_excl, 0.0)  # (K,P)
     out = jax.lax.dot_general(
-        colors, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        colors, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (3,P)
     t_final = jnp.min(jnp.where(alive, t_incl, 1.0), axis=0, keepdims=True)  # (1,P)
     out_ref[0] = out
-    tfin_ref[...] = t_final
+    tfin_ref[0] = t_final
 
 
 def _bwd_kernel(
@@ -119,9 +126,9 @@ def _bwd_kernel(
 ):
     t = pl.program_id(0)
     splats = splats_ref[0]       # (11,K)
-    valid = valid_ref[...]       # (1,K)
+    valid = valid_ref[0]         # (1,K)
     gout = gout_ref[0]           # (3,P)
-    gtfin = gtfin_ref[...]       # (1,P)
+    gtfin = gtfin_ref[0]         # (1,P)
     px, py = _pixel_coords(t, tiles_x, tile_h, tile_w, row_offset)
 
     alpha, alpha_raw, live, t_incl, t_excl, alive, colors, (dx, dy, power) = _alpha_and_trans(
@@ -131,11 +138,13 @@ def _bwd_kernel(
 
     # d colors: out = C @ W  =>  dC = gout @ W^T   (3,P)x(P,K) -> (3,K)
     dcolors = jax.lax.dot_general(
-        gout, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        gout, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (3,K)
     # dW = C^T @ gout : (K,3)x(3,P) -> (K,P)
     dw = jax.lax.dot_general(
-        colors, gout, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        colors, gout, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (K,P)
     dw = jnp.where(alive, dw, 0.0)
 
@@ -176,12 +185,6 @@ def _bwd_kernel(
     dsplats_ref[0] = dsplats
 
 
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 @functools.lru_cache(maxsize=None)
 def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, interpret=None):
     """Build the custom_vjp'd tile compositor for a static tile layout.
@@ -189,8 +192,13 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
     Returned fn: (tile_splats_t (T,11,K) f32, valid (T,K) f32) ->
                  (out (T,3,P) f32, t_final (T,P) f32)
     Differentiable w.r.t. tile_splats_t only (valid gets zero cotangent).
+
+    Inside the kernels ``valid`` and ``t_final`` travel as (T,1,K) and
+    (T,1,P): Mosaic needs a block's last two dims to be (8,128)-aligned or
+    whole, and a (1,K) block of a (T,K) array is neither.
     """
-    interpret = _auto_interpret(interpret)
+    if interpret is None:
+        interpret = interpret_mode()
 
     def _run_fwd(splats_t, valid):
         t_count, _, k = splats_t.shape
@@ -198,23 +206,25 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
         kern = functools.partial(
             _fwd_kernel, tiles_x=tiles_x, tile_h=tile_h, tile_w=tile_w, row_offset=row_offset
         )
-        return pl.pallas_call(
+        out, tfin = pl.pallas_call(
             kern,
             grid=(t_count,),
             in_specs=[
                 pl.BlockSpec((1, 11, k), lambda t: (t, 0, 0)),
-                pl.BlockSpec((1, k), lambda t: (t, 0)),
+                pl.BlockSpec((1, 1, k), lambda t: (t, 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 3, p), lambda t: (t, 0, 0)),
-                pl.BlockSpec((1, p), lambda t: (t, 0)),
+                pl.BlockSpec((1, 1, p), lambda t: (t, 0, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((t_count, 3, p), jnp.float32),
-                jax.ShapeDtypeStruct((t_count, p), jnp.float32),
+                jax.ShapeDtypeStruct((t_count, 1, p), jnp.float32),
             ],
             interpret=interpret,
-        )(splats_t, valid)
+            compiler_params=_COMPILER_PARAMS,
+        )(splats_t, valid[:, None, :])
+        return out, tfin[:, 0, :]
 
     def _run_bwd(splats_t, valid, gout, gtfin):
         t_count, _, k = splats_t.shape
@@ -227,14 +237,15 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
             grid=(t_count,),
             in_specs=[
                 pl.BlockSpec((1, 11, k), lambda t: (t, 0, 0)),
-                pl.BlockSpec((1, k), lambda t: (t, 0)),
+                pl.BlockSpec((1, 1, k), lambda t: (t, 0, 0)),
                 pl.BlockSpec((1, 3, p), lambda t: (t, 0, 0)),
-                pl.BlockSpec((1, p), lambda t: (t, 0)),
+                pl.BlockSpec((1, 1, p), lambda t: (t, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 11, k), lambda t: (t, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((t_count, 11, k), jnp.float32),
             interpret=interpret,
-        )(splats_t, valid, gout, gtfin)
+            compiler_params=_COMPILER_PARAMS,
+        )(splats_t, valid[:, None, :], gout, gtfin[:, None, :])
 
     @jax.custom_vjp
     def composite(splats_t, valid):

@@ -21,6 +21,7 @@ from repro.configs import get_arch
 from repro.obs.clock import now, since
 from repro.configs.common import SHAPES, lm_batch_specs, decode_specs, params_specs
 from repro.launch import hlo_cost
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
 from repro.models import api
 from repro.models.partitioning import batch_pspecs, cache_pspecs, param_pspecs, to_named
@@ -170,6 +171,7 @@ def main():
     ap.add_argument("--no-fsdp", action="store_true")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
     run_dryrun(args.arch, args.shape, multi_pod=args.multi_pod, fsdp=not args.no_fsdp, out_dir=args.out)
 
 

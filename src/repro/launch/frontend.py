@@ -31,6 +31,7 @@ from repro.core.config import GSConfig
 from repro.core.projection import look_at_camera
 from repro.frontend import FrontendClient, Gateway, GatewayThread, SessionManager
 from repro.insitu import TemporalCheckpointStore, timeline_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_gs import init_params_from_volume, load_params_from_ckpt
 from repro.obs import Obs, parse_slo_spec, trace_meta, validate_trace_jsonl, write_trace
 
@@ -120,6 +121,7 @@ def main(argv=None):
                          "(ok/warn/breach + budget burn) shows up in the "
                          "stats and metrics wire messages")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     slo_kw = parse_slo_spec(args.slo) if args.slo else None
 
     if args.smoke:

@@ -23,10 +23,10 @@ import os
 import sys
 import tempfile
 
-import jax
 import numpy as np
 
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.insitu import (
     InsituTrainer,
     TemporalCheckpointStore,
@@ -34,6 +34,7 @@ from repro.insitu import (
     replay_live,
     scrub,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Obs, trace_meta, validate_trace_jsonl, write_trace
 from repro.obs.clock import now, since
 from repro.serve_gs import front_camera
@@ -180,6 +181,7 @@ def main(argv=None):
                          "than --overhead-budget per step")
     ap.add_argument("--overhead-budget", type=float, default=0.25)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.timesteps = min(args.timesteps, 3)
@@ -191,7 +193,7 @@ def main(argv=None):
         args.warm_steps = min(args.warm_steps, 10)
         args.t1 = min(args.t1, 0.15)
 
-    mesh = jax.make_mesh((args.data_par, args.model_par), ("data", "model"))
+    mesh = make_mesh((args.data_par, args.model_par))
     cfg = GSConfig(
         img_h=args.res, img_w=args.res, batch_size=args.batch,
         k_per_tile=128 if args.smoke else 256,

@@ -5,18 +5,13 @@ jax device state.
 """
 from __future__ import annotations
 
-import jax
+from repro.core.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_gs_mesh(n_data: int, n_model: int):
-    """Mesh for distributed 3D-GS runs/benchmarks (paper scaling: 1/2/4 workers)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

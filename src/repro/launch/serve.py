@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api, lm
 
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--cache-len", type=int, default=0, help="default prompt+gen")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mod = get_arch(args.arch)
     cfg = mod.smoke_config() if args.smoke else mod.config()

@@ -24,6 +24,7 @@ from repro.configs.gs_datasets import DATASETS
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
 from repro.core.train import init_state
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Obs, trace_meta, validate_trace_jsonl, write_trace
 from repro.serve_gs import RenderServer, make_clients, run_load
 from repro.volume import datasets as VD
@@ -83,6 +84,7 @@ def main(argv=None):
     ap.add_argument("--trace-capacity", type=int, default=65536,
                     help="span ring size (oldest spans drop beyond this)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.res = min(args.res, 32)

@@ -22,6 +22,7 @@ from repro.core import gaussians as G
 from repro.core.config import GSConfig
 from repro.core.densify import densify_and_rebalance, reset_opacity
 from repro.core.losses import lpips_proxy, psnr, ssim
+from repro.core.sharding import make_mesh
 from repro.core.train import (
     all_gather_bytes_per_step,
     init_state,
@@ -33,6 +34,7 @@ from repro.core.train import (
 )
 from repro.configs.gs_datasets import DATASETS
 from repro.data.views import ViewDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Obs, devmem, new_request_id, trace_meta, validate_trace_jsonl, write_trace
 from repro.obs.clock import now, since
 from repro.volume import datasets as VD
@@ -56,7 +58,10 @@ class GSTrainer:
         pad = (-n0) % quantum
         pts = np.concatenate([np.asarray(points), np.full((pad, 3), 1e6, np.float32)])
         cols = np.concatenate([np.asarray(colors), np.zeros((pad, 3), np.float32)])
-        g = G.init_from_points(jnp.asarray(pts), jnp.asarray(cols), sh_degree=cfg.sh_degree)
+        # scale from the real points: the dead padding at 1e6 would stretch
+        # the bounding box and make every Gaussian the largest allowed
+        g = G.init_from_points(jnp.asarray(pts), jnp.asarray(cols), sh_degree=cfg.sh_degree,
+                               init_scale=G.default_init_scale(points))
         g = g._replace(opacity_logit=g.opacity_logit.at[n0:].set(-20.0))
         self.state = jax.device_put(init_state(g), state_shardings(mesh))
         self._step_fn = None
@@ -195,10 +200,11 @@ def main():
                     help="write final train.* registry snapshot as JSON")
     ap.add_argument("--trace-capacity", type=int, default=65536)
     args = ap.parse_args()
+    enable_compile_cache()
 
     obs = Obs(trace=args.trace_out is not None, trace_capacity=args.trace_capacity)
 
-    mesh = jax.make_mesh((args.data_par, args.model_par), ("data", "model"))
+    mesh = make_mesh((args.data_par, args.model_par))
     cfg = GSConfig(
         img_h=args.res, img_w=args.res, batch_size=args.batch, backend=args.backend,
         k_per_tile=args.k_per_tile, max_steps=max(args.steps, 1),

@@ -217,9 +217,7 @@ def slstm_train(p, cfg, x):
         bspec = baxes if bsz % max(
             1, int(np.prod([mesh.shape[a] for a in baxes]))
         ) == 0 else None
-        from repro.core.sharding import shard_map
-
-        ys = shard_map(  # analysis: allow(retrace.jit_outside_factory, runs under the caller's jitted train step: constructed once per outer trace, not per call)
+        ys = jax.shard_map(  # analysis: allow(retrace.jit_outside_factory, runs under the caller's jitted train step: constructed once per outer trace, not per call)
             _slstm_scan,
             mesh=mesh,
             in_specs=(PS(bspec), PS(), PS()),

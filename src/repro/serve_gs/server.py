@@ -63,6 +63,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
 from repro.core.projection import Camera
+from repro.core.sharding import make_mesh
 from repro.core.train import make_batched_eval_render, make_tile_row_render
 from repro.obs import DEFAULT_SIZE_BUCKETS, Obs
 from repro.obs.clock import now as _now
@@ -211,7 +212,7 @@ class RenderServer:
         # metrics registry (atomic snapshot, one reset) + the span recorder
         # (falsy NULL_RECORDER unless tracing is enabled)
         self.obs = obs if obs is not None else Obs()
-        self.mesh = mesh if mesh is not None else jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh if mesh is not None else make_mesh((1, 1))
         self.pose_quantum = pose_quantum
         self.store_frames = store_frames
         self.frames_capacity = max(int(frames_capacity), 1)

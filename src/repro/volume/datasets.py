@@ -58,15 +58,20 @@ def miranda_like(res: int = 96, extent: float = 1.0, *, modes: int = 6, seed: in
     """Rayleigh-Taylor-style mixing interface: z minus a multi-mode wavy
     displacement field. Isosurface = the turbulent mixing layer (large,
     folded, sheet-like — the structural regime of Miranda)."""
-    x, y, z = _grid(res, extent)
+    # The modes vary in x and y only: sum them on the (x, y) plane and
+    # broadcast along z (the same values as on the full grid, at 1/res of
+    # the work and memory; this is what makes res 512 cheap to generate).
+    lin = np.linspace(-extent, extent, res, dtype=np.float32)
+    x, y = lin[:, None, None], lin[None, :, None]
+    z = lin[None, None, :]
     rng = np.random.default_rng(seed)
-    disp = np.zeros_like(x)
+    disp = np.zeros((res, res, 1), np.float32)
     for _ in range(modes):
         kx, ky = rng.uniform(2.0, 9.0, 2)
         ph1, ph2 = rng.uniform(0, 2 * np.pi, 2)
         amp = rng.uniform(0.04, 0.14)
         disp += amp * np.sin(kx * x + ph1) * np.cos(ky * y + ph2)
     # secondary fold structure (mushroom caps)
-    disp += 0.08 * np.sin(4.0 * x) * np.sin(4.0 * y) * np.cos(3.0 * z)
+    disp = (disp + 0.08 * np.sin(4.0 * x) * np.sin(4.0 * y) * np.cos(3.0 * z)).astype(np.float32)
     field = z - disp
-    return VolumeSpec(field.astype(np.float32), 0.0, extent, "miranda_like")
+    return VolumeSpec(field, 0.0, extent, "miranda_like")

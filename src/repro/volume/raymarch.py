@@ -68,7 +68,7 @@ def render_isosurface(
     dirs_cam = jnp.stack(
         [(xs - cam.cx) / cam.fx, (ys - cam.cy) / cam.fy, jnp.ones_like(xs)], -1
     )
-    dirs = dirs_cam @ R  # cam->world (R rows are world axes of cam frame)
+    dirs = jnp.matmul(dirs_cam, R, precision=jax.lax.Precision.HIGHEST)  # cam->world (R rows are world axes of cam frame)
     dirs = dirs / jnp.linalg.norm(dirs, axis=-1, keepdims=True)
 
     # march from the camera through the volume's bounding sphere
@@ -116,7 +116,7 @@ def render_isosurface(
     )
     n = grad / (jnp.linalg.norm(grad, axis=-1, keepdims=True) + 1e-12)
     l = jnp.asarray(LIGHT_DIR) / jnp.linalg.norm(jnp.asarray(LIGHT_DIR))
-    lam = jnp.clip(-(n @ l), 0.0, 1.0)
+    lam = jnp.clip(-jnp.matmul(n, l, precision=jax.lax.Precision.HIGHEST), 0.0, 1.0)
     color = jnp.asarray(BASE_COLOR) * (AMBIENT + (1 - AMBIENT) * lam[..., None])
     bg_arr = jnp.broadcast_to(jnp.asarray(bg, jnp.float32), color.shape)
     return jnp.clip(jnp.where(hit[..., None], color, bg_arr), 0.0, 1.0)

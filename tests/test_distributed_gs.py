@@ -19,13 +19,14 @@ SCRIPT = textwrap.dedent(
     import jax, numpy as np, jax.numpy as jnp
     from repro.core import gaussians as G
     from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
     from repro.core.train import init_state, make_train_step, state_shardings
     from repro.volume import kingsnake_like, extract_isosurface_points, orbit_cameras, render_isosurface
     from repro.volume.cameras import camera_slice
 
     nd = len(jax.devices())
     shape = {1: (1, 1), 8: (4, 2)}[nd]
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape)
     H = W = 32
     cfg = GSConfig(img_h=H, img_w=W, tile_h=16, tile_w=16, k_per_tile=128, batch_size=4,
                    backend="ref", gather_mode=gather_mode)
@@ -88,10 +89,11 @@ BALANCE_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, numpy as np, jax.numpy as jnp
     from repro.core.train import init_state, record_shard_balance, shard_balance, state_shardings
+    from repro.core.sharding import make_mesh
     from repro.insitu import fixed_capacity_init
     from repro.obs import MetricsRegistry
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4))
     n = 512
     rng = np.random.default_rng(0)
     pts = (rng.normal(size=(n, 3)) * 0.3).astype(np.float32)
@@ -154,12 +156,11 @@ def _insitu_pair_vol():
 
 
 def _tiny_insitu(obs):
-    import jax
-
     from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
     from repro.insitu import InsituTrainer
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(
         img_h=24, img_w=24, tile_h=8, tile_w=8, k_per_tile=32, batch_size=2,
         max_steps=64, densify_from=10**9, opacity_reset_interval=10**9,

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.densify import densify_and_rebalance, reset_opacity, DEAD_LOGIT
 from repro.core.train import init_state, make_train_step, make_eval_render, state_shardings
 from repro.checkpoint import save_checkpoint, restore_checkpoint, latest_step
@@ -15,7 +16,7 @@ from repro.data.views import ViewDataset
 
 
 def _setup(n_points=600, H=32, views=4, res=32):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(img_h=H, img_w=H, tile_h=16, tile_w=16, k_per_tile=128, batch_size=2,
                    densify_from=1, densify_interval=5, densify_until=100)
     vol = kingsnake_like(res=res)

@@ -40,8 +40,12 @@ def test_grad_matches_ref():
 
 
 def test_nonzero_sh_falls_back():
+    """Degree > 0 SH has no Pallas kernel: asking for one raises instead of
+    quietly running the reference; the reference itself serves it."""
     g = make_scene(64, seed=2)
     g = g._replace(sh=jnp.zeros((64, 4, 3)))
     cam = make_cam(32, 32)
-    out = project_packed(g, cam, backend="pallas")  # silently uses ref path
+    with pytest.raises(ValueError, match="degree-0"):
+        project_packed(g, cam, backend="pallas")
+    out = project_packed(g, cam, backend="ref")
     assert out.shape == (64, 11)

@@ -85,10 +85,9 @@ def test_collectives_parsed_with_groups(tmp_path):
         import jax, jax.numpy as jnp, json
         from jax.sharding import PartitionSpec as PS, NamedSharding
         from repro.launch.hlo_cost import analyze
-        from repro.core.sharding import shard_map
         mesh = jax.make_mesh((8,), ("d",))
         def f(x):
-            return shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
+            return jax.shard_map(lambda a: jax.lax.psum(a, "d"), mesh=mesh,
                              in_specs=PS("d"), out_specs=PS())(x)
         x = jax.ShapeDtypeStruct((1024, 64), jnp.float32)
         co = jax.jit(f).lower(x).compile()

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state
 from repro.insitu import (
     InsituTrainer,
@@ -286,7 +287,7 @@ def test_warm_start_fewer_steps_and_zero_retraces():
     """After a small timestep perturbation, warm-start reaches the cold-start
     PSNR in strictly fewer optimization steps, with zero re-traces of the
     train step across timesteps."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(
         img_h=48, img_w=48, batch_size=2, k_per_tile=128, max_steps=200,
         densify_from=10**9, opacity_reset_interval=10**9,

@@ -9,6 +9,7 @@ from repro.core import gaussians as G
 from repro.core import render as R
 from repro.core.config import GSConfig
 from repro.core.losses import psnr
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state, make_batched_eval_render, make_eval_render
 from repro.launch.serve_gs import load_params_from_ckpt
 from repro.serve_gs import (
@@ -146,7 +147,7 @@ def test_cache_lru_eviction_and_stats():
 
 # ------------------------------------------------- batched render + server
 def test_batched_eval_render_matches_single():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(img_h=H, img_w=W, k_per_tile=128)
     g = make_scene(n=256, scale=0.06)
     cams = orbit_cameras(3, img_h=H, img_w=W)

@@ -5,6 +5,7 @@ import numpy as np
 
 from repro.core import gaussians as G
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.train import init_state, make_train_step, state_shardings
 from repro.core import projection as P
 from repro.core import render as R
@@ -42,7 +43,7 @@ def test_training_with_sh2_improves_view_dependent_target():
     g = G.init_from_points(jnp.asarray(pts), sh_degree=2, init_scale=0.06)
     assert g.sh.shape == (n, 9, 3)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     cfg = GSConfig(img_h=32, img_w=32, k_per_tile=128, batch_size=2, sh_degree=2)
     # two opposing cameras with different target tints = view-dependent GT
     cams = P.Camera(

@@ -1,12 +1,12 @@
 """Tile-granular serving tests: byte-budgeted content-deduplicating cache,
 bitwise tile-path equivalence (assembly, strips, partial renders), dirty-row
 invalidation, and the cache-key resolution regression."""
-import jax
 import numpy as np
 import pytest
 
 from repro.core import projection as P
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.core.train import make_batched_eval_render, make_tile_row_render
 from repro.serve_gs import (
     FrameCache,
@@ -117,7 +117,7 @@ def test_same_pose_different_resolution_never_shares_cache(tmp_path):
 # ==================================================== bitwise tile-path suite
 def test_strip_render_rows_bitwise_equal_full_frame():
     cfg = GSConfig(img_h=H, img_w=W, k_per_tile=64)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     g = make_scene(n=256, scale=0.06)
     cam = make_cam(H, W)
     full = np.asarray(make_batched_eval_render(mesh, cfg)(g, stack_cameras([cam])))[0]
