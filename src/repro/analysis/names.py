@@ -30,9 +30,10 @@ cross-checks:
     at the site: ``# analysis: declare(train.devmem.*)``.
 
 ``names.unknown_span`` / ``names.unrecorded_stage``
-    A ``record(rid, "<span>")`` literal outside ``STAGES``/``TRAIN_STAGES``,
-    and a vocabulary stage never recorded anywhere (exporters lay Perfetto
-    lanes from the vocabulary — a dead stage is a dead lane).
+    A ``record(rid, "<span>")`` (or ``span``/``instant``) literal outside
+    ``STAGES``/``TRAIN_STAGES``, and a vocabulary stage never recorded
+    anywhere (exporters lay Perfetto lanes from the vocabulary — a dead
+    stage is a dead lane).
 
 Dynamic registrations with a static dotted prefix (``f"server.lod_rows.l
 {lvl}"``) register the family ``server.lod_rows.l*``; doc names may use
@@ -168,7 +169,7 @@ class _Extractor(ast.NodeVisitor):
                         self.vocab.dynamic_unresolved.append(
                             (self.sf.relpath, node.lineno, ctx)
                         )
-        elif attr in ("record", "instant") and len(node.args) >= 2:
+        elif attr in ("record", "instant", "span") and len(node.args) >= 2:
             arg = node.args[1]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
                 self.vocab.spans_recorded.append(

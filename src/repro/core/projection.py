@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import gaussians as G
+from repro.core.scopes import scoped
 
 # Packed splat layout (dim PACKED_DIM along last axis)
 MX, MY, CA, CB, CC, OP, CR, CG, CB_, DEPTH, RAD = range(11)
@@ -52,6 +53,7 @@ def look_at_camera(eye, target, up, fx, fy, cx, cy) -> Camera:
     return Camera(viewmat, jnp.float32(fx), jnp.float32(fy), jnp.float32(cx), jnp.float32(cy))
 
 
+@scoped("project")
 def project(
     g: G.GaussianModel,
     cam: Camera,
@@ -197,6 +199,7 @@ def project_bounds_np(
     return mx, my, rad
 
 
+@scoped("depth_sort")
 def sort_by_depth(packed: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Depth-sort packed splats front-to-back. Returns (sorted_packed, order).
 

@@ -14,12 +14,14 @@ import jax.numpy as jnp
 
 from repro.core import gaussians as G
 from repro.core import projection as P
+from repro.core.scopes import scoped
 from repro.kernels.tile_raster import ops as raster_ops
 
 BIG_IDX = jnp.iinfo(jnp.int32).max
 
 
 @partial(jax.jit, static_argnames=("img_h", "img_w", "tile_h", "tile_w", "k_per_tile", "chunk"))
+@scoped("binning")
 def build_tile_lists(
     packed_sorted: jax.Array,  # (N, 11) depth-sorted splats
     *,
@@ -87,6 +89,7 @@ def build_tile_lists(
     jax.jit,
     static_argnames=("img_h", "img_w", "tile_h", "tile_w", "k_per_tile", "block", "k_block_mult", "chunk"),
 )
+@scoped("binning")
 def build_tile_lists_hier(
     packed_sorted: jax.Array,
     *,

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh
 
+from repro.core.scopes import scoped
+
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...] = ("data", "model"), *,
               devices=None) -> Mesh:
@@ -108,6 +110,7 @@ def ssim_l1_sums(
     return jnp.sum(ssim_map), l1_sum, count
 
 
+@scoped("loss")
 def distributed_gs_loss(
     pred: jax.Array,
     gt: jax.Array,

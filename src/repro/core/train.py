@@ -269,34 +269,37 @@ def make_train_step(
             params, probe
         )
 
-        # ---- the paper's fused all-reduce: ONE collective over packed grads
-        flat, unpack = pack_pytree(grads)
-        flat = jax.lax.psum(flat, data_axes)
-        grads = unpack(flat)
-        # view-space positional gradient stats for densification
-        g2d = jnp.sqrt(jnp.sum(probe_grad * probe_grad, axis=-1) + 1e-20)  # (B_l, probe_n)
-        if gather_mode == "params3d":
-            g2d = jax.lax.dynamic_slice_in_dim(
-                g2d, jax.lax.axis_index(model_axis) * n_local, n_local, axis=1
-            )
-        g2d = jax.lax.psum(jnp.sum(g2d, axis=0), data_axes)
-        visible = radii > 0.0
-        vis = jax.lax.psum(jnp.sum(visible.astype(jnp.float32), axis=0), data_axes)
-        maxr = jax.lax.pmax(jnp.max(radii, axis=0), data_axes)
+        with jax.named_scope("grad_reduce"):
+            # ---- the paper's fused all-reduce: ONE collective over packed grads
+            flat, unpack = pack_pytree(grads)
+            flat = jax.lax.psum(flat, data_axes)
+            grads = unpack(flat)
+            # view-space positional gradient stats for densification
+            g2d = jnp.sqrt(jnp.sum(probe_grad * probe_grad, axis=-1) + 1e-20)  # (B_l, probe_n)
+            if gather_mode == "params3d":
+                g2d = jax.lax.dynamic_slice_in_dim(
+                    g2d, jax.lax.axis_index(model_axis) * n_local, n_local, axis=1
+                )
+            g2d = jax.lax.psum(jnp.sum(g2d, axis=0), data_axes)
+            visible = radii > 0.0
+            vis = jax.lax.psum(jnp.sum(visible.astype(jnp.float32), axis=0), data_axes)
+            maxr = jax.lax.pmax(jnp.max(radii, axis=0), data_axes)
 
-        # ---- sharded Adam update (per-field LRs; Grendel sqrt-batch scaling)
-        scale = grendel_lr_scale(cfg.batch_size) if cfg.grendel_sqrt_lr_scaling else 1.0
-        lr_means = expon_lr(
-            state.step, lr_init=cfg.lr_means_init, lr_final=cfg.lr_means_final, max_steps=cfg.max_steps
-        )
-        lrs = G.GaussianModel(
-            means=lr_means * scale,
-            log_scales=cfg.lr_scales * scale,
-            quats=cfg.lr_quats * scale,
-            opacity_logit=cfg.lr_opacity * scale,
-            sh=cfg.lr_sh * scale,
-        )
-        new_params, new_adam = adam_update(grads, state.adam, params, lrs)
+        with jax.named_scope("adam"):
+            # ---- sharded Adam update (per-field LRs; Grendel sqrt-batch scaling)
+            scale = grendel_lr_scale(cfg.batch_size) if cfg.grendel_sqrt_lr_scaling else 1.0
+            lr_means = expon_lr(
+                state.step, lr_init=cfg.lr_means_init, lr_final=cfg.lr_means_final,
+                max_steps=cfg.max_steps,
+            )
+            lrs = G.GaussianModel(
+                means=lr_means * scale,
+                log_scales=cfg.lr_scales * scale,
+                quats=cfg.lr_quats * scale,
+                opacity_logit=cfg.lr_opacity * scale,
+                sh=cfg.lr_sh * scale,
+            )
+            new_params, new_adam = adam_update(grads, state.adam, params, lrs)
 
         new_state = GSTrainState(
             params=new_params,

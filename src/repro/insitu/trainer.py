@@ -41,7 +41,7 @@ from repro.core.train import (
     shard_balance,
     state_shardings,
 )
-from repro.obs import Obs, devmem, new_request_id
+from repro.obs import NO_SPAN, Obs, devmem, new_request_id
 from repro.obs.clock import now, since
 from repro.data.views import ViewDataset
 from repro.volume.datasets import VolumeSpec
@@ -251,12 +251,12 @@ class InsituTrainer:
 
     def _eval_psnr(self, data: ViewDataset) -> float:
         rec = self.obs.trace
-        t0 = now() if rec else 0.0
-        cam, gt = data.view(self.eval_view % self.n_views)
-        img, _ = self._eval_fn(self.state.params, cam)
-        p = float(psnr(img, gt))
-        if rec:
-            rec.record(self._rid, "eval", t0, now(), psnr=round(p, 3))
+        with rec.span(self._rid, "eval") if rec else NO_SPAN as sp:
+            cam, gt = data.view(self.eval_view % self.n_views)
+            img, _ = self._eval_fn(self.state.params, cam)
+            p = float(psnr(img, gt))
+            if rec:
+                sp.meta["psnr"] = round(p, 3)
         self.obs.metrics.gauge("train.psnr").set(round(p, 4))
         return p
 
@@ -278,16 +278,21 @@ class InsituTrainer:
         if self.eval_every > 0:
             curve.append((0, psnr0))  # already measured by the caller
         rid = self._rid
-        t_iter = now()
-        for i, (cams, gt) in enumerate(data.batches(self.cfg.batch_size, steps=steps)):
+        batches = iter(data.batches(self.cfg.batch_size, steps=steps))
+        for i in range(steps):
             rec = self.obs.trace  # re-read: tracing may toggle mid-fit
+            with rec.span(rid, "batch", step=i) if rec else NO_SPAN as sp:
+                got = next(batches, None)
+                if got is None:
+                    sp.drop()  # the stream ended early: no batch was assembled
+            if got is None:
+                break
+            cams, gt = got
             t_batch = now()
-            if rec:
-                rec.record(rid, "batch", t_iter, t_batch, step=i)
-            self.state, metrics = self._step_fn(self.state, cams, gt)
+            with rec.span(rid, "dispatch", step=i) if rec else NO_SPAN:
+                self.state, metrics = self._step_fn(self.state, cams, gt)
             if rec:
                 t_disp = now()
-                rec.record(rid, "dispatch", t_batch, t_disp, step=i)
                 jax.block_until_ready(self.state)
                 t_dev = now()
                 rec.record(rid, "device", t_disp, t_dev, step=i)
@@ -298,7 +303,6 @@ class InsituTrainer:
             step_ms.observe(since(t_batch) * 1e3)
             if self.eval_every > 0 and (i + 1) % self.eval_every == 0:
                 curve.append((i + 1, self._eval_psnr(data)))
-            t_iter = now()
         return loss, curve
 
     def reset(self) -> None:
@@ -457,10 +461,8 @@ class InsituTrainer:
             out.append(rep)
             rec = self.obs.trace
             if store is not None:
-                t0 = now() if rec else 0.0
-                store.append(rep.t_index, self.state.params)
-                if rec:
-                    rec.record(self._rid, "ckpt", t0, now(), t_index=rep.t_index)
+                with rec.span(self._rid, "ckpt", t_index=rep.t_index) if rec else NO_SPAN:
+                    store.append(rep.t_index, self.state.params)
             if server is not None:
                 t0 = now() if rec else 0.0
                 params = jax.tree_util.tree_map(np.asarray, self.state.params)

@@ -223,6 +223,7 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
             ],
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
+            name="tile_raster_fwd",
         )(splats_t, valid[:, None, :])
         return out, tfin[:, 0, :]
 
@@ -245,6 +246,7 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
             out_shape=jax.ShapeDtypeStruct((t_count, 11, k), jnp.float32),
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
+            name="tile_raster_bwd",
         )(splats_t, valid[:, None, :], gout, gtfin[:, None, :])
 
     @jax.custom_vjp

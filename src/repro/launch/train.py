@@ -35,7 +35,15 @@ from repro.core.train import (
 from repro.configs.gs_datasets import DATASETS
 from repro.data.views import ViewDataset
 from repro.launch.compile_cache import enable_compile_cache
-from repro.obs import Obs, devmem, new_request_id, trace_meta, validate_trace_jsonl, write_trace
+from repro.obs import (
+    NO_SPAN,
+    Obs,
+    devmem,
+    new_request_id,
+    trace_meta,
+    validate_trace_jsonl,
+    write_trace,
+)
 from repro.obs.clock import now, since
 from repro.volume import datasets as VD
 from repro.volume.isosurface import extract_isosurface_points
@@ -103,16 +111,21 @@ class GSTrainer:
         gb = all_gather_bytes_per_step(self.cfg, self.mesh, self.state.params.n)
         losses = []
         t0 = now()
-        t_iter = t0
-        for i, (cams, gt) in enumerate(data.batches(self.cfg.batch_size, steps=steps)):
+        batches = iter(data.batches(self.cfg.batch_size, steps=steps))
+        for i in range(steps):
             rec = self.obs.trace
+            with rec.span(rid, "batch", step=i) if rec else NO_SPAN as sp:
+                got = next(batches, None)
+                if got is None:
+                    sp.drop()  # the stream ended early: no batch was assembled
+            if got is None:
+                break
+            cams, gt = got
             t_batch = now()
-            if rec:
-                rec.record(rid, "batch", t_iter, t_batch, step=i)
-            self.state, metrics = self.step_fn(self.state, cams, gt)
+            with rec.span(rid, "dispatch", step=i) if rec else NO_SPAN:
+                self.state, metrics = self.step_fn(self.state, cams, gt)
             if rec:
                 t_disp = now()
-                rec.record(rid, "dispatch", t_batch, t_disp, step=i)
                 jax.block_until_ready(self.state)
                 t_dev = now()
                 rec.record(rid, "device", t_disp, t_dev, step=i)
@@ -124,15 +137,14 @@ class GSTrainer:
             step_ms.observe(since(t_batch) * 1e3)
             step = int(self.state.step)
             if densify and self.cfg.densify_from <= step <= self.cfg.densify_until and step % self.cfg.densify_interval == 0:
-                t_d = now()
-                self.state, report = densify_and_rebalance(
-                    self.state, self.cfg, n_shards=self.n_shards, scene_extent=scene_extent
-                )
-                self.state = jax.device_put(self.state, state_shardings(self.mesh))
                 rec = self.obs.trace
-                if rec:
-                    rec.record(rid, "densify", t_d, now(), step=step,
-                               n=int(self.state.params.n))
+                with rec.span(rid, "densify", step=step) if rec else NO_SPAN as sp:
+                    self.state, report = densify_and_rebalance(
+                        self.state, self.cfg, n_shards=self.n_shards, scene_extent=scene_extent
+                    )
+                    self.state = jax.device_put(self.state, state_shardings(self.mesh))
+                    if rec:
+                        sp.meta["n"] = int(self.state.params.n)
                 gb = all_gather_bytes_per_step(self.cfg, self.mesh, self.state.params.n)
                 self.shard_balance()  # densify is where shards skew
                 if self.verbose:
@@ -146,7 +158,6 @@ class GSTrainer:
                     f"step_ms p50 {snap['train.step_ms']['p50']:.1f} "
                     f"({since(t0):.1f}s)"
                 )
-            t_iter = now()
         self.shard_balance()
         devmem.record(m)
         return losses
@@ -155,18 +166,19 @@ class GSTrainer:
         eval_fn = make_eval_render(self.mesh, self.cfg)
         rec = self.obs.trace
         rid = new_request_id()
-        t0 = now() if rec else 0.0
-        ps, ss, lp = [], [], []
-        for i in view_ids:
-            cam, gt = data.view(int(i))
-            img, _ = eval_fn(self.state.params, cam)
-            ps.append(float(psnr(img, gt)))
-            ss.append(float(ssim(img, gt)))
-            lp.append(float(lpips_proxy(img, gt)))
-        out = {"psnr": float(np.mean(ps)), "ssim": float(np.mean(ss)), "lpips_proxy": float(np.mean(lp))}
-        self.obs.metrics.gauge("train.psnr").set(round(out["psnr"], 4))
-        if rec:
-            rec.record(rid, "eval", t0, now(), views=len(ps), psnr=round(out["psnr"], 3))
+        with rec.span(rid, "eval") if rec else NO_SPAN as sp:
+            ps, ss, lp = [], [], []
+            for i in view_ids:
+                cam, gt = data.view(int(i))
+                img, _ = eval_fn(self.state.params, cam)
+                ps.append(float(psnr(img, gt)))
+                ss.append(float(ssim(img, gt)))
+                lp.append(float(lpips_proxy(img, gt)))
+            out = {"psnr": float(np.mean(ps)), "ssim": float(np.mean(ss)),
+                   "lpips_proxy": float(np.mean(lp))}
+            self.obs.metrics.gauge("train.psnr").set(round(out["psnr"], 4))
+            if rec:
+                sp.meta.update(views=len(ps), psnr=round(out["psnr"], 3))
         return out
 
 
@@ -225,10 +237,8 @@ def main():
     print(f"train {train_time:.1f}s  final-loss {losses[-1]:.5f}  {metrics}")
     if args.ckpt:
         rec, rid = obs.trace, new_request_id()
-        t_c = now()
-        path = save_checkpoint(args.ckpt, int(tr.state.step), tr.state)
-        if rec:
-            rec.record(rid, "ckpt", t_c, now(), step=int(tr.state.step))
+        with rec.span(rid, "ckpt", step=int(tr.state.step)) if rec else NO_SPAN:
+            path = save_checkpoint(args.ckpt, int(tr.state.step), tr.state)
         print("checkpoint:", path)
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:

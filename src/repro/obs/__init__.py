@@ -29,6 +29,7 @@ from repro.obs.metrics import (
 )
 from repro.obs.slo import SLOTracker, parse_slo_spec
 from repro.obs.trace import (
+    NO_SPAN,
     NULL_RECORDER,
     STAGES,
     TRAIN_STAGES,
@@ -36,6 +37,7 @@ from repro.obs.trace import (
     Span,
     TraceRecorder,
     new_request_id,
+    unwatch_compiles,
 )
 from repro.obs import devmem
 
@@ -52,6 +54,7 @@ __all__ = [
     "TraceRecorder",
     "NullRecorder",
     "NULL_RECORDER",
+    "NO_SPAN",
     "Span",
     "STAGES",
     "TRAIN_STAGES",
@@ -94,4 +97,5 @@ class Obs:
         return self.trace
 
     def disable_trace(self) -> None:
+        unwatch_compiles(self.trace)
         self.trace = NULL_RECORDER

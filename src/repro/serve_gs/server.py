@@ -65,7 +65,7 @@ from repro.core.config import GSConfig
 from repro.core.projection import Camera
 from repro.core.sharding import make_mesh
 from repro.core.train import make_batched_eval_render, make_tile_row_render
-from repro.obs import DEFAULT_SIZE_BUCKETS, Obs
+from repro.obs import DEFAULT_SIZE_BUCKETS, NO_SPAN, Obs, new_request_id
 from repro.obs.clock import now as _now
 from repro.serve_gs.batcher import (
     MicroBatch,
@@ -709,84 +709,84 @@ class RenderServer:
                     self._c_lod_rows[lvl].inc()
         self._timestep_requests[int(timestep)] = self._timestep_requests.get(int(timestep), 0) + 1
         rec = self.obs.trace
-
-        tiles = None
-        if self.tile_cache and not self.cache.disabled:
-            # fast path: the stitched frame itself is cached (zero-copy hit)
-            frame = self.cache.get(tile_key(key, ASSEMBLED))
-            if frame is not None:
-                self._full_hits.inc()
-                if rec:
-                    rec.record(req.request_id, "submit", t, _now(),
-                               outcome="full_hit", level=level, timestep=int(timestep))
-                fut = FrameFuture(self, key, req)
-                fut._resolve(frame)
-                return fut
-            tiles = [
-                self.cache.get(tile_key(key if row_keys is None else row_keys[ti // self.tiles_x], ti))
-                for ti in range(self.n_tiles)
-            ]
-            if all(t is not None for t in tiles):  # full hit: assemble once
-                self._full_hits.inc()
-                a0 = _now()
-                frame = self._assemble(tiles)
-                self.cache.put(tile_key(key, ASSEMBLED), frame, dedup=False)
-                if rec:
-                    a1 = _now()
-                    rec.record(req.request_id, "submit", t, a0,
-                               outcome="full_hit", level=level, timestep=int(timestep))
-                    rec.record(req.request_id, "assemble", a0, a1, tiles=self.n_tiles)
-                fut = FrameFuture(self, key, req)
-                fut._resolve(frame)
-                return fut
-        else:
-            frame = self.cache.get(key)
-            if frame is not None:
-                if rec:
-                    rec.record(req.request_id, "submit", t, _now(),
-                               outcome="cache_hit", level=level, timestep=int(timestep))
-                fut = FrameFuture(self, key, req)
-                fut._resolve(frame)
-                return fut
-        fut = self._pending.get(key)
-        if fut is not None:  # identical pose already in flight: render once
-            fut._attach(req)
-            self._deduped.inc()
-            if rec:
-                rec.record(req.request_id, "submit", t, _now(),
-                           outcome="dedup", primary=fut.request_id,
-                           level=level, timestep=int(timestep))
-            return fut
-        fut = FrameFuture(self, key, req)
-        req.future = fut
-        self._pending[key] = fut
-        if tiles is not None and (row_levels is not None or any(t is not None for t in tiles)):
-            # partial hit: a dedicated job renders only the missing tile rows.
-            # Mixed-level frames always take this path — the batcher's full-
-            # frame renders are single-level, but the strip renderer already
-            # knows how to fill each row at its own level.
-            got = sum(1 for x in tiles if x is not None)
-            if got:
-                self._partial_hits.inc()
+        # the ring's submit span starts at the request's due time; its
+        # profiler region covers the probe and the enqueue below
+        with rec.span(req.request_id, "submit", t0=t) if rec else NO_SPAN as sp:
+            tiles = None
+            if self.tile_cache and not self.cache.disabled:
+                with rec.span(req.request_id, "cache", op="probe") if rec else NO_SPAN:
+                    # fast path: the stitched frame itself is cached (zero-copy hit)
+                    frame = self.cache.get(tile_key(key, ASSEMBLED))
+                    if frame is None:
+                        tiles = [
+                            self.cache.get(
+                                tile_key(key if row_keys is None else row_keys[ti // self.tiles_x], ti)
+                            )
+                            for ti in range(self.n_tiles)
+                        ]
+                if frame is not None:
+                    self._full_hits.inc()
+                    if rec:
+                        sp.meta.update(outcome="full_hit", level=level, timestep=int(timestep))
+                    fut = FrameFuture(self, key, req)
+                    fut._resolve(frame)
+                    return fut
+                if all(t is not None for t in tiles):  # full hit: assemble once
+                    self._full_hits.inc()
+                    with rec.span(req.request_id, "assemble", tiles=self.n_tiles) if rec else NO_SPAN as asm:
+                        frame = self._assemble(tiles)
+                        with rec.span(req.request_id, "cache", op="put") if rec else NO_SPAN:
+                            self.cache.put(tile_key(key, ASSEMBLED), frame, dedup=False)
+                    if rec:  # submit ends where assembly begins
+                        sp.t1 = asm.t0
+                        sp.meta.update(outcome="full_hit", level=level, timestep=int(timestep))
+                    fut = FrameFuture(self, key, req)
+                    fut._resolve(frame)
+                    return fut
             else:
-                self._frame_misses.inc()
-            if rec:
-                rec.record(req.request_id, "submit", t, _now(),
-                           outcome="partial_hit" if got else "miss",
-                           missing_tiles=self.n_tiles - got,
-                           level=level, timestep=int(timestep),
-                           foveated=row_levels is not None)
-            self._partial.append(
-                _PartialJob(req=req, fut=fut, tiles=tiles, row_levels=row_levels, row_keys=row_keys)
-            )
-        else:
-            if self.tile_cache:
-                self._frame_misses.inc()
-            if rec:
-                rec.record(req.request_id, "submit", t, _now(),
-                           outcome="miss", level=level, timestep=int(timestep))
-            self.batcher.submit(req)
-        return fut
+                frame = self.cache.get(key)
+                if frame is not None:
+                    if rec:
+                        sp.meta.update(outcome="cache_hit", level=level, timestep=int(timestep))
+                    fut = FrameFuture(self, key, req)
+                    fut._resolve(frame)
+                    return fut
+            fut = self._pending.get(key)
+            if fut is not None:  # identical pose already in flight: render once
+                fut._attach(req)
+                self._deduped.inc()
+                if rec:
+                    sp.meta.update(outcome="dedup", primary=fut.request_id,
+                                   level=level, timestep=int(timestep))
+                return fut
+            fut = FrameFuture(self, key, req)
+            req.future = fut
+            self._pending[key] = fut
+            if tiles is not None and (row_levels is not None or any(t is not None for t in tiles)):
+                # partial hit: a dedicated job renders only the missing tile rows.
+                # Mixed-level frames always take this path — the batcher's full-
+                # frame renders are single-level, but the strip renderer already
+                # knows how to fill each row at its own level.
+                got = sum(1 for x in tiles if x is not None)
+                if got:
+                    self._partial_hits.inc()
+                else:
+                    self._frame_misses.inc()
+                if rec:
+                    sp.meta.update(outcome="partial_hit" if got else "miss",
+                                   missing_tiles=self.n_tiles - got,
+                                   level=level, timestep=int(timestep),
+                                   foveated=row_levels is not None)
+                self._partial.append(
+                    _PartialJob(req=req, fut=fut, tiles=tiles, row_levels=row_levels, row_keys=row_keys)
+                )
+            else:
+                if self.tile_cache:
+                    self._frame_misses.inc()
+                if rec:
+                    sp.meta.update(outcome="miss", level=level, timestep=int(timestep))
+                self.batcher.submit(req)
+            return fut
 
     # ------------------------------------------------------------- tile path
     def _assemble(self, tiles: list) -> np.ndarray:
@@ -863,6 +863,9 @@ class RenderServer:
         """Render a partial hit's missing tile rows — each at its assigned
         level for foveated jobs — then assemble and resolve."""
         req = job.req
+        rec = self.obs.trace
+        if rec:  # the job leaves the queue here
+            rec.record(req.request_id, "queue", req.t_submit, _now())
         entry = self._entry(req.timestep)
         cam_np = jax.tree_util.tree_map(np.asarray, req.cam)
         lvl_of = (lambda r: job.row_levels[r]) if job.row_levels is not None else (lambda r: req.level)
@@ -879,15 +882,16 @@ class RenderServer:
         self._c_dispatch_s.add(_now() - t0)
         for r, dev in launched:
             strip = np.asarray(jax.block_until_ready(dev))  # (tile_h, W, 3)
-            for tx in range(self.tiles_x):
-                ti = r * self.tiles_x + tx
-                if job.tiles[ti] is None:
-                    tile = np.ascontiguousarray(
-                        strip[:, tx * self.tile_w : (tx + 1) * self.tile_w]
-                    )
-                    tile.setflags(write=False)
-                    self.cache.put(tile_key(key_of(r), ti), tile)
-                    job.tiles[ti] = tile
+            with rec.span(req.request_id, "cache", op="put") if rec else NO_SPAN:
+                for tx in range(self.tiles_x):
+                    ti = r * self.tiles_x + tx
+                    if job.tiles[ti] is None:
+                        tile = np.ascontiguousarray(
+                            strip[:, tx * self.tile_w : (tx + 1) * self.tile_w]
+                        )
+                        tile.setflags(write=False)
+                        self.cache.put(tile_key(key_of(r), ti), tile)
+                        job.tiles[ti] = tile
         now = _now()
         self._c_block_s.add(now - t0)
         self._c_render_s.add(now - max(t0, self._busy_until))
@@ -897,15 +901,14 @@ class RenderServer:
         if missing:
             units = sum(self.keep_ratio ** lvl_of(r) for r in missing)
             self._update_row_cost((now - t0) * 1e3 / units)
-        rec = self.obs.trace
         if rec:
             rec.record(req.request_id, "render", t0, now,
                        partial=True, rows=len(missing), level=req.level,
                        foveated=job.row_levels is not None)
-        frame = self._assemble(job.tiles)
-        self.cache.put(tile_key(req.cache_key, ASSEMBLED), frame, dedup=False)
-        if rec:
-            rec.record(req.request_id, "assemble", now, _now(), tiles=self.n_tiles)
+        with rec.span(req.request_id, "assemble", tiles=self.n_tiles) if rec else NO_SPAN:
+            frame = self._assemble(job.tiles)
+            with rec.span(req.request_id, "cache", op="put") if rec else NO_SPAN:
+                self.cache.put(tile_key(req.cache_key, ASSEMBLED), frame, dedup=False)
         fut = self._pending.pop(req.cache_key, None)
         if fut is not None:
             return fut._resolve(frame)
@@ -918,12 +921,20 @@ class RenderServer:
         mb: MicroBatch | None = self.batcher.next_batch()
         if mb is None:
             return False
+        rec = self.obs.trace
+        if rec:  # each request leaves the queue here
+            t_out = _now()
+            for req in mb.requests:
+                rec.record(req.request_id, "queue", req.t_submit, t_out)
         entry = self._entry(mb.timestep)
-        t0 = _now()
-        imgs = self._level_render[mb.level](
-            entry.level_params[mb.level], jax.tree_util.tree_map(np.asarray, mb.cams)
-        )
-        self._c_dispatch_s.add(_now() - t0)
+        # a batch's launch is no one request's: its span roots a tree of its own
+        with rec.span(new_request_id(), "dispatch", batch=len(mb.requests),
+                      bucket=mb.bucket) if rec else NO_SPAN:
+            t0 = _now()
+            imgs = self._level_render[mb.level](
+                entry.level_params[mb.level], jax.tree_util.tree_map(np.asarray, mb.cams)
+            )
+            self._c_dispatch_s.add(_now() - t0)
         self._render_calls.inc()
         self._batch_sizes.observe(len(mb.requests))
         self._ring.append(_InFlight(mb, imgs, t0))
@@ -951,19 +962,18 @@ class RenderServer:
             frame = imgs[i].copy()  # own buffer: never pin the whole batch
             frame.setflags(write=False)  # shared with cache + deduped waiters
             if rec:
-                r0 = _now()
-            self._cache_put_frame(req.cache_key, frame)
-            fut = self._pending.pop(req.cache_key, None)
-            if fut is not None:
-                done += fut._resolve(frame)
-            else:  # pragma: no cover - defensive: request outside the table
-                self._complete(req, frame)
-                done += 1
-            if rec:
                 rec.record(req.request_id, "render", inf.t_dispatch, now,
                            batch=len(inf.mb.requests), bucket=inf.mb.bucket,
                            level=inf.mb.level, timestep=inf.mb.timestep)
-                rec.record(req.request_id, "retire", r0, _now())
+            with rec.span(req.request_id, "retire") if rec else NO_SPAN:
+                with rec.span(req.request_id, "cache", op="put") if rec else NO_SPAN:
+                    self._cache_put_frame(req.cache_key, frame)
+                fut = self._pending.pop(req.cache_key, None)
+                if fut is not None:
+                    done += fut._resolve(frame)
+                else:  # pragma: no cover - defensive: request outside the table
+                    self._complete(req, frame)
+                    done += 1
         return done
 
     def step(self) -> int:

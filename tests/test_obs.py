@@ -9,10 +9,13 @@ import threading
 import time
 import tracemalloc
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import projection as P
 from repro.core.config import GSConfig
+from repro.core.sharding import make_mesh
 from repro.frontend import (
     AsyncFrontendClient,
     FrontendClient,
@@ -248,28 +251,58 @@ def test_chrome_overlapping_spans_spill_into_sublanes_and_meta_rides_along():
 
 
 # ===================================================== zero-cost-when-off
-def test_tracing_disabled_allocates_nothing_and_frames_are_bitwise():
+class _Views:
+    """Two fixed views for ``GSTrainer.fit``."""
+
+    def __init__(self):
+        self.cams = P.Camera(*[jnp.stack(x) for x in zip(
+            make_cam(H, W, dist=2.3), make_cam(H, W, dist=2.6))])
+        self.gt = jnp.zeros((2, H, W, 3), jnp.float32)
+
+    def batches(self, batch_size, *, steps):
+        for _ in range(steps):
+            yield self.cams, self.gt
+
+
+def test_tracing_disabled_allocates_nothing_and_frames_are_bitwise(monkeypatch):
     """The two acceptance guarantees of the no-op recorder: with tracing off
-    a render allocates NOTHING in the recorder module, and enabling tracing
-    changes no pixel of the rendered frame."""
+    a render and a train step allocate NOTHING in the recorder module and
+    read no clock there (the span regions, the queue and cache spans, the
+    compile listener), and enabling tracing changes no pixel of the
+    rendered frame."""
+    import repro.obs.trace as trace_mod
+    from repro.launch.train import GSTrainer
+
     srv = RenderServer(
         make_scene(n=128, scale=0.06), GSConfig(img_h=H, img_w=W, k_per_tile=64),
         n_levels=1, max_batch=2, store_frames=False,
     )
+    g = make_scene(n=128, scale=0.06)
+    tr = GSTrainer(GSConfig(img_h=H, img_w=W, k_per_tile=64, batch_size=2, pad_quantum=128),
+                   make_mesh((1, 1)), np.asarray(g.means), np.full((128, 3), 0.5, np.float32),
+                   verbose=False)
+    views = _Views()
     with srv:
-        assert srv.obs.trace is NULL_RECORDER
+        assert srv.obs.trace is NULL_RECORDER and tr.obs.trace is NULL_RECORDER
         cam = make_cam(H, W, dist=2.3)
         srv.submit(cam).result()  # compile + warm every code path
         srv.cache.drop(lambda k: True)
+        tr.fit(views, steps=1, densify=False)
+        clock_reads = []
+        real_now = trace_mod.now
+        monkeypatch.setattr(trace_mod, "now", lambda: clock_reads.append(1) or real_now())
 
         tracemalloc.start()
         s1 = tracemalloc.take_snapshot()
         frame_off = srv.submit(cam).result()
+        tr.fit(views, steps=1, densify=False)
         s2 = tracemalloc.take_snapshot()
         tracemalloc.stop()
         filt = [tracemalloc.Filter(True, "*obs/trace*")]
         diff = s2.filter_traces(filt).compare_to(s1.filter_traces(filt), "lineno")
         assert sum(abs(d.size_diff) for d in diff) == 0, diff
+        assert clock_reads == []
+        monkeypatch.setattr(trace_mod, "now", real_now)
 
         srv.obs.enable_trace()
         srv.cache.drop(lambda k: True)
@@ -304,11 +337,17 @@ def traced_gt():
         yield gt
 
 
+# spans that root a tree of their own, outside any request's: a batch's
+# launch and a compile
+_OWN_TREE = ("dispatch", "compile")
+
+
 def _trees(spans) -> dict:
-    """{rid: [spans in record order]}"""
+    """{rid: [spans in record order]} of the requests"""
     trees = {}
     for s in spans:
-        trees.setdefault(s.rid, []).append(s)
+        if s.name not in _OWN_TREE:
+            trees.setdefault(s.rid, []).append(s)
     for v in trees.values():
         v.sort(key=lambda s: s.seq)
     return trees
@@ -342,14 +381,21 @@ def test_tcp_miss_then_cache_hit_span_trees(traced_gt):
         spans = _wait_spans(
             rec, lambda ss: sum(1 for s in ss if s.name == "write") >= 2
         )
+    dispatches = [s for s in spans if s.name == "dispatch"]
+    assert len(dispatches) == 1 and dispatches[0].meta["batch"] == 1
     trees = _trees(spans)
     assert len(trees) == 2
     rid_miss, rid_hit = sorted(trees)
 
     miss = trees[rid_miss]
     assert [s.name for s in miss] == [
-        "admit", "coalesce", "submit", "render", "retire", "encode", "write",
+        "admit", "coalesce", "cache", "submit", "queue", "render", "cache", "retire",
+        "encode", "write",
     ]
+    probe, put = _named(miss, "cache")
+    assert probe.meta["op"] == "probe" and put.meta["op"] == "put"
+    (que,) = _named(miss, "queue")
+    assert que.t0 == _named(miss, "submit")[0].t0 and que.t1 >= que.t0
     (sub,) = _named(miss, "submit")
     assert sub.meta["outcome"] == "miss"
     (adm,) = _named(miss, "admit")
@@ -362,7 +408,7 @@ def test_tcp_miss_then_cache_hit_span_trees(traced_gt):
         assert s.t1 >= s.t0
 
     hit = trees[rid_hit]
-    assert [s.name for s in hit] == ["admit", "coalesce", "submit", "encode", "write"]
+    assert [s.name for s in hit] == ["admit", "coalesce", "cache", "submit", "encode", "write"]
     (sub,) = _named(hit, "submit")
     assert sub.meta["outcome"] in ("full_hit", "cache_hit")
     assert not _named(hit, "render")
@@ -371,7 +417,7 @@ def test_tcp_miss_then_cache_hit_span_trees(traced_gt):
     text = spans_to_jsonl(spans)
     assert validate_trace_jsonl(text) == len(spans)
     rids = {json.loads(x)["rid"] for x in text.splitlines()}
-    assert rids == {rid_miss, rid_hit}
+    assert rids == {s.rid for s in spans}
 
 
 def test_tcp_dedup_span_points_at_primary_request(traced_gt):
@@ -431,7 +477,8 @@ def test_tcp_partial_tile_hit_span_tree(traced_gt):
     (tree,) = _trees(spans).values()
     names = [s.name for s in tree]
     assert names == [
-        "admit", "coalesce", "submit", "render", "assemble", "encode", "write",
+        "admit", "coalesce", "cache", "submit", "queue", "cache", "render", "cache", "assemble",
+        "encode", "write",
     ]
     (sub,) = _named(tree, "submit")
     assert sub.meta["outcome"] == "partial_hit"
@@ -481,7 +528,8 @@ def test_tcp_shed_request_emits_terminated_span():
         assert sh.meta["terminated"] is True and sh.t1 >= sh.t0
     for rid in set(trees) - shed_rids:
         assert [s.name for s in trees[rid]] == [
-            "admit", "coalesce", "submit", "render", "retire", "encode", "write",
+            "admit", "coalesce", "cache", "submit", "queue", "render", "cache", "retire",
+            "encode", "write",
         ]
 
 
