@@ -199,15 +199,38 @@ def project_bounds_np(
     return mx, my, rad
 
 
+@jax.custom_vjp
+def permute_rows(x: jax.Array, order: jax.Array) -> jax.Array:
+    """``x[order]`` for a permutation ``order``, whose backward is the
+    cotangent gathered through the inverse permutation. Autodiff of the plain
+    gather would transpose it to a scatter-add, which does not know that
+    ``order`` is a permutation; a row gather of the same shape is cheaper."""
+    return x[order]
+
+
+def _permute_rows_fwd(x, order):
+    return x[order], order
+
+
+def _permute_rows_bwd(order, g):
+    # the inverse permutation by an int32 sort of the order
+    return g[jnp.argsort(order)], None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
 @scoped("depth_sort")
 def sort_by_depth(packed: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Depth-sort packed splats front-to-back. Returns (sorted_packed, order).
 
     The ordering is treated as non-differentiable (as in the CUDA 3D-GS
-    rasterizer): gradients flow through the gathered values, not the order.
+    rasterizer): gradients flow through the gathered values, not the order,
+    and go back as a gather through the inverse permutation
+    (``permute_rows``).
     """
     depth = jax.lax.stop_gradient(packed[:, DEPTH])
     # depth is > near or +inf, and non-negative floats order like their int32
     # bit patterns; XLA compiles an int32 sort for the TPU in half the time
     order = jnp.argsort(jax.lax.bitcast_convert_type(depth, jnp.int32))
-    return packed[order], order
+    return permute_rows(packed, order), order

@@ -17,7 +17,8 @@ __all__ = ["STAGE_SCOPES", "scoped"]
 
 STAGE_SCOPES = (
     "project",      # P.project: 3D Gaussians -> packed 2D splats
-    "depth_sort",   # P.sort_by_depth: the sort and the permutation gather
+    "depth_sort",   # P.sort_by_depth: the sort, the permutation gather and,
+                    # backward, the gather through the inverse permutation
     "binning",      # R.build_tile_lists(_hier): the per-tile front-most-K scan
     "tile_gather",  # each tile's splats gathered for the compositor
     "raster",       # the tile compositor (Pallas kernel or the jnp oracle)

@@ -42,7 +42,7 @@ def programs():
     gt = jnp.zeros((2, H, W, 3), jnp.float32)
     step = make_train_step(mesh, cfg).lower(state, cams, gt).compile().as_text()
     render = make_batched_eval_render(mesh, cfg).lower(state.params, cams).compile().as_text()
-    return {"train": op_names(step), "render": op_names(render)}
+    return {"train": op_names(step), "render": op_names(render), "train_hlo": step}
 
 
 @pytest.mark.parametrize("scope", STAGE_SCOPES)
@@ -61,6 +61,15 @@ def test_backward_stages_read_transpose_of_their_scope(programs, scope):
     views are vmapped, so the path reads ``transpose(jvp(vmap(<scope>)))``."""
     pattern = re.compile(r"(^|/)transpose\(jvp\((vmap\()?%s\)+(/|$)" % scope)
     assert any(pattern.search(p) for p in programs["train"]), scope
+
+
+def test_depth_sort_backward_has_no_scatter(programs):
+    """The depth sort's backward is a gather through the inverse permutation:
+    no scatter of the compiled train step carries the ``depth_sort`` scope."""
+    scatters = [line for line in programs["train_hlo"].splitlines()
+                if re.search(r"\bscatter\(", line)
+                and any("depth_sort" in stages_in(p) for p in op_names(line))]
+    assert not scatters, scatters[:3]
 
 
 @pytest.mark.parametrize("program", ["train", "render"])

@@ -81,6 +81,45 @@ def test_tile_lists_cover_naive(seed):
     np.testing.assert_allclose(np.asarray(img_t), np.asarray(img_n), atol=1e-6)
 
 
+def _plain_depth_sort(packed):
+    """``sort_by_depth``'s order, with autodiff's own transpose of the gather."""
+    order = P.sort_by_depth(jax.lax.stop_gradient(packed))[1]
+    return packed[order], order
+
+
+@pytest.mark.parametrize("views,depths", [(None, "distinct"), (4, "distinct"), (4, "inf"),
+                                          (None, "tied")])
+def test_depth_sort_gradient_equals_plain_gather(views, depths):
+    """The gradient through ``sort_by_depth`` (a gather through the inverse
+    permutation) equals autodiff of ``packed[order]`` element for element:
+    one view or vmapped views, invalid splats at +inf depth, tied depths."""
+    r = np.random.default_rng(7)
+    shape = (500, P.PACKED_DIM) if views is None else (views, 500, P.PACKED_DIM)
+    packed = r.normal(0, 1, shape).astype(np.float32)
+    depth = r.uniform(0.2, 5.0, shape[:-1]).astype(np.float32)
+    if depths == "inf":
+        depth[..., ::3] = np.inf
+    if depths == "tied":
+        depth = np.round(depth)
+    packed[..., P.DEPTH] = depth
+    w = jnp.asarray(r.normal(0, 1, shape).astype(np.float32))
+
+    def loss(sort):
+        fn = sort if views is None else jax.vmap(sort)
+
+        def f(x):
+            y = fn(x)[0]
+            return jnp.sum(jnp.where(jnp.isfinite(y), y * w, 0.0))
+
+        return f
+
+    x = jnp.asarray(packed)
+    got, want = (jax.jit(jax.value_and_grad(loss(s)))(x)
+                 for s in (P.sort_by_depth, _plain_depth_sort))
+    assert float(got[0]) == float(want[0])
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), depth_scale=st.floats(0.5, 2.0))
 def test_projection_depth_ordering(seed, depth_scale):
