@@ -54,6 +54,11 @@ MEANS_MAX_LR_MULT = 2.0
 # The (1, 4) step against the (1, 1) step: same kernels and inputs, the
 # loss sums only reduce in another order.
 MESH_LOSS_TOL = 2e-5
+# Each leaf's first-step gradient, (1, 4) against (1, 1), as the norm of the
+# difference over the (1, 1) norm: the same float32 terms summed in another
+# order. A gradient scaled by the number of shards reads 3. Adam's updates
+# hardly see such a scale, which is why the loss and the means cannot.
+MESH_GRAD_TOL = 1e-3
 STATE_SHARE_TOL = 0.02   # each of 4 devices holds 1/4 +- this of the state
 FRAME_SLACK = 1e-6       # served frames lie in [0, 1] up to float rounding
 
@@ -317,7 +322,8 @@ def state_bytes_per_device(state) -> dict:
 
 
 def four_chips(devs, pts, cols, data) -> None:
-    """The sharded step on a (1, 4) mesh against the same step on device 0."""
+    """The sharded step on a (1, 4) mesh against the same step on device 0:
+    the loss and each leaf's first gradient, read from Adam's first moment."""
     import jax
     import numpy as np
 
@@ -331,14 +337,15 @@ def four_chips(devs, pts, cols, data) -> None:
                 for shape, d in meshes.items()}
     compile_phase({f"train step {shape}": (tr.step_fn, (tr.state, cams, gt))
                    for shape, tr in trainers.items()})
-    losses, means = {}, {}
+    losses, grads = {}, {}
+    b1 = 0.9  # repro.optim.adam's first-moment decay: m = (1 - b1) g after one step
     for shape, tr in trainers.items():
         t0 = time.perf_counter()
         state, metrics = tr.step_fn(tr.state, cams, gt)
         jax.block_until_ready(state)
         dt = time.perf_counter() - t0
         losses[shape] = float(metrics["loss"])
-        means[shape] = np.asarray(state.params.means)
+        grads[shape] = {k: np.asarray(v) / (1 - b1) for k, v in state.adam.m._asdict().items()}
         print(f"mesh {shape}: {state.params.n} Gaussians, loss {losses[shape]:.7f}, "
               f"step {dt:.4f} s")
         if shape == (1, 4):
@@ -354,10 +361,14 @@ def four_chips(devs, pts, cols, data) -> None:
                         f"device {d.id} holds {held.get(d, 0) / total:.4f} of the state, not 1/4")
         del state, metrics
     diff = abs(losses[(1, 4)] - losses[(1, 1)])
-    means_max = float(np.max(np.abs(means[(1, 4)] - means[(1, 1)])))
-    print(f"mesh: loss diff (1, 4) vs (1, 1) {diff:.3e} (tol {MESH_LOSS_TOL:g}); "
-          f"updated means max diff {means_max:.3e}")
+    print(f"mesh: loss diff (1, 4) vs (1, 1) {diff:.3e} (tol {MESH_LOSS_TOL:g})")
     require(np.isfinite(diff) and diff <= MESH_LOSS_TOL, "the (1, 4) step differs from one chip")
+    for leaf, want in grads[(1, 1)].items():
+        gap = float(np.linalg.norm(grads[(1, 4)][leaf] - want) / max(np.linalg.norm(want), 1e-30))
+        print(f"mesh: first-step gradient of {leaf}, (1, 4) vs (1, 1): relative gap {gap:.3e} "
+              f"(tol {MESH_GRAD_TOL:g})")
+        require(np.isfinite(gap) and gap <= MESH_GRAD_TOL,
+                f"the (1, 4) step's gradient of {leaf} differs from one chip's")
 
 
 def main(argv=None) -> None:

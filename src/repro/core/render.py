@@ -78,7 +78,9 @@ def build_tile_lists(
         merged = jnp.sort(jnp.concatenate([best, cand], axis=1), axis=1)[:, : best.shape[1]]
         return merged, None
 
-    init = jnp.full((t_count, k_per_tile), BIG_IDX, jnp.int32)
+    # typed like the splats (full_like), so that inside a shard_map the
+    # scan's carry varies over the same mesh axes as what it merges
+    init = jnp.full_like(mx, BIG_IDX, dtype=jnp.int32, shape=(t_count, k_per_tile))
     best, _ = jax.lax.scan(step, init, jnp.arange(n_chunks))
     valid = best != BIG_IDX
     idx = jnp.where(valid, best, 0)

@@ -23,6 +23,8 @@ STAGE_SCOPES = (
     "tile_gather",  # each tile's splats gathered for the compositor
     "raster",       # the tile compositor (Pallas kernel or the jnp oracle)
     "loss",         # distributed_gs_loss: L1 + D-SSIM and its reductions
+    "splat_exchange",  # exchange_splats: the model-axis all_gather of the
+                    # splats (or 3D state) and, backward, its psum_scatter
     "grad_reduce",  # packed-gradient psum and the densification statistics
     "adam",         # learning-rate schedule and the Adam update
 )

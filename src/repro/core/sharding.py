@@ -10,12 +10,18 @@ Mapping (see DESIGN.md §5):
 
 Communication per step (all JAX-native collectives inside shard_map):
   all_gather(projected splats, "model")   owner shard -> renderers (11 floats
-                                          per Gaussian, not the full 3D state)
+                                          per Gaussian, not the full 3D state;
+                                          ``exchange_splats``)
   psum_scatter(splat grads, "model")      renderers -> owner shard (implicit:
                                           this is just the autodiff transpose
                                           of the all_gather)
+  psum(loss sums, "data" + "model")       the global loss, replicated
   psum(packed param grads, "data")        the paper's fused all-reduce
   ppermute(strip halos, "model")          distributed SSIM boundary exchange
+
+The train step's ``shard_map`` checks which mesh axes each value varies
+over (``check_vma``), so autodiff transposes each collective by its type:
+the replicated loss hands every shard an unsummed cotangent.
 """
 from __future__ import annotations
 
@@ -36,6 +42,15 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...] = ("data", "model"),
     under which they do). ``devices`` picks a subset, e.g. one chip of four.
     """
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices)
+
+
+@scoped("splat_exchange")
+def exchange_splats(x: jax.Array, axis_name: str, *, axis: int) -> jax.Array:
+    """Every model shard's rows of ``x``, concatenated along ``axis`` in
+    shard order: what each shard owns goes to every renderer. Backward, the
+    transpose is a psum_scatter that hands each owner the cotangent of its
+    own rows, summed over the renderers."""
+    return jax.lax.all_gather(x, axis_name, axis=axis, tiled=True)
 
 
 def halo_exchange_rows(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
@@ -122,7 +137,10 @@ def distributed_gs_loss(
     """(1-lam)*L1 + lam*D-SSIM over globally distributed pixels.
 
     ``pred``/``gt``: (B_local, h_local, W, 3). Returns the *global* scalar
-    loss (replicated) — psum over ``reduce_axes``.
+    loss, replicated: the sums are psum'd over ``reduce_axes``, the axes the
+    pixels are spread over. Under ``check_vma`` that psum transposes to a
+    cotangent each shard takes as it is, so the gradient is that of the one
+    global loss on every mesh.
     """
     def per_view(p, g):
         return ssim_l1_sums(p, g, strip_axis)

@@ -6,6 +6,10 @@ One jitted step = shard_map over the (data, model) mesh:
   L1+D-SSIM -> backward (all_gather transposes to psum_scatter) -> fused
   psum of packed grads over "data" -> sharded Adam update.
 
+The step's shard_map checks which mesh axes each value varies over
+(``check_vma=True``), so each collective transposes by its type and every
+shard's gradient is that of the one global loss, on any mesh shape.
+
 The "replicated baseline" of the paper (single-GPU semantics, data-parallel
 only) is the same code on a mesh with model=1.
 """
@@ -22,7 +26,7 @@ from repro.core import gaussians as G
 from repro.core import projection as P
 from repro.core import render as R
 from repro.core.config import GSConfig
-from repro.core.sharding import distributed_gs_loss
+from repro.core.sharding import distributed_gs_loss, exchange_splats
 from repro.optim.adam import AdamState, adam_init, adam_update
 from repro.optim.schedules import expon_lr, grendel_lr_scale
 from repro.utils.tree import pack_pytree
@@ -194,7 +198,10 @@ def make_train_step(
     gather_mode = resolve_gather_mode(cfg, mesh, data_axes=data_axes, model_axis=model_axis)
 
     def local_step(state: GSTrainState, cams: P.Camera, gt: jax.Array):
-        params = state.params
+        # each data shard differentiates the loss of its own views: the
+        # gradient varies over the data axes until the fused psum below
+        # (Adam updates state.params, which the data axes replicate)
+        params = jax.lax.pcast(state.params, data_axes, to="varying")
         n_local = params.means.shape[0]
         b_local = gt.shape[0]
 
@@ -208,7 +215,7 @@ def make_train_step(
                     [p.means, p.log_scales, p.quats, p.opacity_logit[:, None],
                      p.sh.reshape(n_local, -1)], axis=1,
                 )
-                flat_all = jax.lax.all_gather(flat3d, model_axis, axis=0, tiled=True)
+                flat_all = exchange_splats(flat3d, model_axis, axis=0)
                 n_total = flat_all.shape[0]
                 sh_k = p.sh.shape[1]
                 p_full = G.GaussianModel(
@@ -232,7 +239,7 @@ def make_train_step(
                 packed = jax.vmap(proj_one)(cams)                  # (B_l, n_local, 11)
                 packed = packed + jnp.pad(probe, ((0, 0), (0, 0), (0, P.PACKED_DIM - 2)))
                 radii_local = packed[..., P.RAD]                   # (B_l, n_local)
-                gathered = jax.lax.all_gather(packed, model_axis, axis=1, tiled=True)
+                gathered = exchange_splats(packed, model_axis, axis=1)
 
             if strip:
                 off = (jax.lax.axis_index(model_axis) * strip_h).astype(jnp.float32)
@@ -263,8 +270,17 @@ def make_train_step(
             )
             return loss, radii_local
 
-        probe_n = n_local * m if gather_mode == "params3d" else n_local
-        probe = jnp.zeros((b_local, probe_n, 2), jnp.float32)
+        # The probe's gradient is each splat's view-space positional gradient
+        # over the whole view. It varies over the views' axes; over the model
+        # axis only where each shard projects its own splats. Under params3d
+        # every shard probes all N splats of its own strip, so the probe is
+        # replicated over the model axis and its gradient summed over strips.
+        if gather_mode == "params3d":
+            probe_n, probe_axes = n_local * m, tuple(data_axes)
+        else:
+            probe_n, probe_axes = n_local, all_axes
+        probe = jax.lax.pcast(jnp.zeros((b_local, probe_n, 2), jnp.float32), probe_axes,
+                              to="varying")
         (loss, radii), (grads, probe_grad) = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
             params, probe
         )
@@ -299,7 +315,7 @@ def make_train_step(
                 opacity_logit=cfg.lr_opacity * scale,
                 sh=cfg.lr_sh * scale,
             )
-            new_params, new_adam = adam_update(grads, state.adam, params, lrs)
+            new_params, new_adam = adam_update(grads, state.adam, state.params, lrs)
 
         new_state = GSTrainState(
             params=new_params,
@@ -332,7 +348,7 @@ def make_train_step(
         mesh=mesh,
         in_specs=(st_specs, cam_spec, gt_spec),
         out_specs=(st_specs, {"loss": PS()}),
-        check_vma=False,
+        check_vma=True,
     )
     # Pin output shardings to the exact NamedShardings of state_shardings():
     # on size-1 mesh axes XLA otherwise normalizes some outputs to PS(), so

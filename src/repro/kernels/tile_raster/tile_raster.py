@@ -197,12 +197,11 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
     (T,1,P): Mosaic needs a block's last two dims to be (8,128)-aligned or
     whole, and a (1,K) block of a (T,K) array is neither.
     """
-    if interpret is None:
-        interpret = interpret_mode()
-
     def _run_fwd(splats_t, valid):
         t_count, _, k = splats_t.shape
         p = tile_h * tile_w
+        vma = jax.typeof(splats_t).vma  # inside a shard_map: the outputs vary as the splats do
+        interp = interpret_mode(varying=bool(vma)) if interpret is None else interpret
         kern = functools.partial(
             _fwd_kernel, tiles_x=tiles_x, tile_h=tile_h, tile_w=tile_w, row_offset=row_offset
         )
@@ -218,10 +217,10 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
                 pl.BlockSpec((1, 1, p), lambda t: (t, 0, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((t_count, 3, p), jnp.float32),
-                jax.ShapeDtypeStruct((t_count, 1, p), jnp.float32),
+                jax.ShapeDtypeStruct((t_count, 3, p), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((t_count, 1, p), jnp.float32, vma=vma),
             ],
-            interpret=interpret,
+            interpret=interp,
             compiler_params=_COMPILER_PARAMS,
             name="tile_raster_fwd",
         )(splats_t, valid[:, None, :])
@@ -230,6 +229,8 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
     def _run_bwd(splats_t, valid, gout, gtfin):
         t_count, _, k = splats_t.shape
         p = tile_h * tile_w
+        vma = jax.typeof(splats_t).vma
+        interp = interpret_mode(varying=bool(vma)) if interpret is None else interpret
         kern = functools.partial(
             _bwd_kernel, tiles_x=tiles_x, tile_h=tile_h, tile_w=tile_w, row_offset=row_offset
         )
@@ -243,8 +244,8 @@ def make_composite(tiles_x: int, tile_h: int, tile_w: int, row_offset: int, inte
                 pl.BlockSpec((1, 1, p), lambda t: (t, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 11, k), lambda t: (t, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((t_count, 11, k), jnp.float32),
-            interpret=interpret,
+            out_shape=jax.ShapeDtypeStruct((t_count, 11, k), jnp.float32, vma=vma),
+            interpret=interp,
             compiler_params=_COMPILER_PARAMS,
             name="tile_raster_bwd",
         )(splats_t, valid[:, None, :], gout, gtfin[:, None, :])

@@ -28,6 +28,20 @@ def test_interpret_mode_follows_platform(monkeypatch, backend, expect):
         assert platform.interpret_mode() is expect
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_interpret_mode_for_operands_that_vary_over_mesh_axes(monkeypatch, backend):
+    """Inside a shard_map that checks mesh-axis types, the CPU interprets a
+    kernel with the TPU interpreter; the chip compiles it as ever."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(platform.jax, "default_backend", lambda: backend)
+    got = platform.interpret_mode(varying=True)
+    if backend == "tpu":
+        assert got is False
+    else:
+        assert isinstance(got, pltpu.InterpretParams)
+
+
 @pytest.mark.parametrize("env_dir", [None, "custom"])
 def test_compile_cache_dir(tmp_path, env_dir):
     """The variable wins when set; otherwise a fixed directory in the

@@ -82,6 +82,85 @@ def test_params3d_gather_equals_projected():
     np.testing.assert_allclose(l_3d, l_proj, atol=5e-6)
 
 
+# ================================================= first-step gradients
+GRAD_SCRIPT = textwrap.dedent(
+    """
+    import os, sys, json
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np, jax.numpy as jnp
+    from repro.core import gaussians as G
+    from repro.core.config import GSConfig
+    from repro.core.sharding import make_mesh
+    from repro.core.train import init_state, make_train_step, state_shardings
+    from repro.volume import kingsnake_like, extract_isosurface_points, orbit_cameras, render_isosurface
+    from repro.volume.cameras import camera_slice
+
+    H = W = 64  # four strips of one 16-px tile row on a (1, 4) mesh
+    B1 = 0.9    # Adam's first-moment decay: m = (1 - b1) g after one step from m = 0
+    vol = kingsnake_like(res=32)
+    pts, nrm, cols = extract_isosurface_points(vol, max_points=800, seed=0)
+    cams = orbit_cameras(4, img_h=H, img_w=W)
+    gts = jnp.stack([
+        render_isosurface(jnp.asarray(vol.field), vol.isovalue, camera_slice(cams, i), img_h=H, img_w=W, n_steps=48)
+        for i in range(4)
+    ])
+    n0 = pts.shape[0]
+    pad = (-n0) % 512
+    pts = np.concatenate([pts, np.full((pad, 3), 1e6, np.float32)])
+    cols = np.concatenate([cols, np.zeros((pad, 3), np.float32)])
+    g = G.init_from_points(jnp.asarray(pts), jnp.asarray(cols), init_scale=0.06)
+    g = g._replace(opacity_logit=g.opacity_logit.at[n0:].set(-20.0))
+    out = {}
+    for mode in ("projected", "params3d"):
+        for shape in ((1, 1), (1, 4), (2, 2), (4, 1)):
+            mesh = make_mesh(shape, devices=jax.devices()[: shape[0] * shape[1]])
+            cfg = GSConfig(img_h=H, img_w=W, tile_h=16, tile_w=16, k_per_tile=64, batch_size=4,
+                           backend="ref", gather_mode=mode)
+            state = jax.device_put(init_state(g), state_shardings(mesh))
+            new, _ = make_train_step(mesh, cfg)(state, cams, gts)
+            got = {k: (np.asarray(v) / (1 - B1)).tolist() for k, v in new.adam.m._asdict().items()}
+            got.update(grad2d_accum=np.asarray(new.grad2d_accum).tolist(),
+                       vis_count=np.asarray(new.vis_count).tolist(),
+                       max_radii=np.asarray(new.max_radii).tolist())
+            out[f"{mode} {shape[0]}x{shape[1]}"] = got
+    print(json.dumps(out))
+    """
+)
+
+
+@pytest.fixture(scope="module")
+def first_step():
+    """Each mesh's first-step gradient (Adam's first moment over 1 - b1) and
+    densification statistics, from one state and one batch of four views."""
+    env = dict(os.environ, PYTHONPATH="src")
+    r = subprocess.run(
+        [sys.executable, "-c", GRAD_SCRIPT],
+        capture_output=True, text=True, timeout=900, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+# The meshes sum the same float32 terms in another order (the loss's psum,
+# the gather's reduce-scatter, the data-axis psum): about 2e-7 of a leaf's
+# norm. A gradient scaled by the number of shards reads 3 or more.
+GRAD_RTOL = 1e-5
+
+
+@pytest.mark.parametrize("mode", ["projected", "params3d"])
+@pytest.mark.parametrize("mesh", ["1x4", "2x2", "4x1"])
+def test_first_step_gradient_is_the_one_chip_gradient(first_step, mode, mesh):
+    """Every mesh hands Adam the gradient of the one global loss, and
+    accumulates the same densification statistics, leaf by leaf."""
+    want, got = first_step[f"{mode} 1x1"], first_step[f"{mode} {mesh}"]
+    assert set(got) == set(want)
+    for leaf in want:
+        w, g = np.asarray(want[leaf]), np.asarray(got[leaf])
+        gap = np.linalg.norm(g - w) / max(np.linalg.norm(w), 1e-30)
+        assert gap <= GRAD_RTOL, (leaf, gap)
+
+
 # ====================================================== shard-balance gauges
 BALANCE_SCRIPT = textwrap.dedent(
     """

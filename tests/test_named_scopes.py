@@ -55,10 +55,13 @@ def test_batched_render_carries_every_render_stage_scope(programs, scope):
     assert any(scope in stages_in(p) for p in programs["render"]), scope
 
 
-@pytest.mark.parametrize("scope", ["tile_gather", "depth_sort", "raster", "project", "loss"])
+@pytest.mark.parametrize("scope", ["tile_gather", "depth_sort", "raster", "project", "loss",
+                                   "splat_exchange"])
 def test_backward_stages_read_transpose_of_their_scope(programs, scope):
     """The gathers' backward scatter-adds keep their stage's name: the
-    views are vmapped, so the path reads ``transpose(jvp(vmap(<scope>)))``."""
+    views are vmapped, so the path reads ``transpose(jvp(vmap(<scope>)))``;
+    the splat exchange runs outside the vmap, and its psum_scatter reads
+    ``transpose(jvp(splat_exchange))``."""
     pattern = re.compile(r"(^|/)transpose\(jvp\((vmap\()?%s\)+(/|$)" % scope)
     assert any(pattern.search(p) for p in programs["train"]), scope
 
