@@ -68,6 +68,18 @@ def state_shardings(mesh: Mesh, model_axis: str = "model"):
     )
 
 
+def _pixel_strips(mesh: Mesh, cfg: GSConfig, model_axis: str = "model") -> bool:
+    """Whether the train step splits each view's pixel rows over ``model_axis``."""
+    return cfg.pixel_parallel and mesh.shape[model_axis] > 1
+
+
+def gt_sharding(mesh: Mesh, cfg: GSConfig, *, data_axes=("data",), model_axis="model") -> NamedSharding:
+    """Where the train step reads its (B, H, W, 3) ground truth: views over
+    ``data_axes`` and, under :func:`_pixel_strips`, row strips over ``model_axis``."""
+    strip = _pixel_strips(mesh, cfg, model_axis)
+    return NamedSharding(mesh, PS(data_axes, model_axis) if strip else PS(data_axes))
+
+
 def resolve_gather_mode(cfg: GSConfig, mesh: Mesh, *, data_axes=("data",), model_axis="model") -> str:
     """The comm schedule ``make_train_step`` will actually use (resolves
     ``"auto"`` exactly like the step builder does)."""
@@ -186,7 +198,7 @@ def make_train_step(
     for a in data_axes:
         d *= mesh.shape[a]
     m = mesh.shape[model_axis]
-    strip = cfg.pixel_parallel and m > 1
+    strip = _pixel_strips(mesh, cfg, model_axis)
     if strip:
         assert cfg.img_h % (m * cfg.tile_h) == 0, "img_h must split into model-axis strips of whole tiles"
     assert cfg.batch_size % d == 0, "global batch must divide data axes"
@@ -341,7 +353,7 @@ def make_train_step(
         max_radii=PS(model_axis),
     )
     cam_spec = P.Camera(*([PS(data_axes)] * 5))
-    gt_spec = PS(data_axes, model_axis) if strip else PS(data_axes)
+    gt_spec = gt_sharding(mesh, cfg, data_axes=data_axes, model_axis=model_axis).spec
 
     stepped = jax.shard_map(
         local_step,

@@ -2,7 +2,9 @@
 
 The paper trains against 448 synthetic orbit views; rendering those GT images
 (ray-marched isosurface) is expensive, so they are produced once and cached
-on disk, then served as shuffled batches sharded onto the mesh.
+on disk, then served as shuffled host batches. The train loop draws each
+batch while the previous step runs and puts its GT straight into the step's
+sharding (:func:`draw_batch`).
 """
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.projection import Camera
+from repro.obs import NO_SPAN
 from repro.volume.cameras import camera_slice, orbit_cameras
 from repro.volume.datasets import VolumeSpec
 from repro.volume.raymarch import render_isosurface
@@ -62,7 +65,7 @@ class ViewDataset:
                 np.save(cache_file, self.gt)
 
     def batches(self, batch_size: int, *, steps: int):
-        """Yield (Camera batch, gt batch) `steps` times (with replacement
+        """Yield (Camera batch, host numpy gt batch) `steps` times (with replacement
         across epochs, without within an epoch — 3D-GS convention). When an
         epoch runs low the next permutation is *prepended*, so the leftover
         views are still drawn before any view repeats: every view is sampled
@@ -82,7 +85,23 @@ class ViewDataset:
                             break
                 sel.append(order.pop())
             sel = np.asarray(sel)
-            yield camera_slice(self.cams, jnp.asarray(sel)), jnp.asarray(self.gt[sel])
+            # a host index: indexing with a device array waits for the work
+            # in flight on the device, and the draw must overlap the step
+            yield camera_slice(self.cams, sel), self.gt[sel]
 
     def view(self, i: int):
         return camera_slice(self.cams, i), jnp.asarray(self.gt[i])
+
+
+def draw_batch(batches, sharding, rec, rid: int, step: int):
+    """The next ``(cams, gt)`` of the iterator ``batches``, with ``gt`` (host
+    numpy or a ``jax.Array``) put on ``sharding``, the train step's own, so
+    each device receives only its block; timed as step ``step``'s ``batch``
+    span on ``rec``. None once the stream has ended."""
+    with rec.span(rid, "batch", step=step) if rec else NO_SPAN as sp:
+        got = next(batches, None)
+        if got is None:
+            sp.drop()  # the stream ended: no batch was assembled
+            return None
+        cams, gt = got
+        return cams, jax.device_put(gt, sharding)

@@ -34,6 +34,7 @@ from repro.core.losses import psnr
 from repro.core.train import (
     GSTrainState,
     all_gather_bytes_per_step,
+    gt_sharding,
     init_state,
     make_eval_render,
     make_train_step,
@@ -43,7 +44,7 @@ from repro.core.train import (
 )
 from repro.obs import NO_SPAN, Obs, devmem, new_request_id
 from repro.obs.clock import now, since
-from repro.data.views import ViewDataset
+from repro.data.views import ViewDataset, draw_batch
 from repro.volume.datasets import VolumeSpec
 from repro.volume.isosurface import extract_isosurface_points
 
@@ -262,7 +263,8 @@ class InsituTrainer:
 
     def _fit(self, data: ViewDataset, steps: int, *, psnr0: float) -> tuple[float, list]:
         """The optimization loop of one timestep, instrumented per step:
-        ``batch`` (host view assembly) -> ``dispatch`` (jitted call returns
+        ``batch`` (host view assembly and upload, drawn while the previous
+        step runs, as in ``GSTrainer.fit``) -> ``dispatch`` (jitted call returns
         under async dispatch) -> ``device`` (bounded by block_until_ready,
         traced runs only — an untraced run keeps jax's dispatch overlap and
         the step stays bitwise identical either way). Wall per step always
@@ -273,26 +275,28 @@ class InsituTrainer:
         device_ms = m.histogram("train.device_ms")
         loss_gauge = m.gauge("train.loss")
         steps_total = m.counter("train.steps")
+        ahead = m.counter("train.batches_ahead")
         curve = []
         loss = float("nan")
         if self.eval_every > 0:
             curve.append((0, psnr0))  # already measured by the caller
         rid = self._rid
+        place = gt_sharding(self.mesh, self.cfg)
         batches = iter(data.batches(self.cfg.batch_size, steps=steps))
+        got = draw_batch(batches, place, self.obs.trace, rid, 0) if steps > 0 else None
         for i in range(steps):
-            rec = self.obs.trace  # re-read: tracing may toggle mid-fit
-            with rec.span(rid, "batch", step=i) if rec else NO_SPAN as sp:
-                got = next(batches, None)
-                if got is None:
-                    sp.drop()  # the stream ended early: no batch was assembled
             if got is None:
-                break
+                break  # the stream ended early
             cams, gt = got
+            rec = self.obs.trace  # re-read: tracing may toggle mid-fit
             t_batch = now()
             with rec.span(rid, "dispatch", step=i) if rec else NO_SPAN:
                 self.state, metrics = self._step_fn(self.state, cams, gt)
+            t_disp = now()
+            got = draw_batch(batches, place, rec, rid, i + 1) if i + 1 < steps else None
+            if got is not None:
+                ahead.inc()
             if rec:
-                t_disp = now()
                 jax.block_until_ready(self.state)
                 t_dev = now()
                 rec.record(rid, "device", t_disp, t_dev, step=i)

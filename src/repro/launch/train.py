@@ -25,6 +25,7 @@ from repro.core.losses import lpips_proxy, psnr, ssim
 from repro.core.sharding import make_mesh
 from repro.core.train import (
     all_gather_bytes_per_step,
+    gt_sharding,
     init_state,
     make_eval_render,
     make_train_step,
@@ -33,7 +34,7 @@ from repro.core.train import (
     state_shardings,
 )
 from repro.configs.gs_datasets import DATASETS
-from repro.data.views import ViewDataset
+from repro.data.views import ViewDataset, draw_batch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (
     NO_SPAN,
@@ -100,32 +101,40 @@ class GSTrainer:
         block_until_ready, traced runs only) -> densify rounds. The
         ``log_every`` print reads ONE atomic registry snapshot instead of
         loose locals, so what it prints is exactly what ``--metrics-out``
-        exports."""
+        exports.
+
+        Batch i + 1 is drawn and uploaded straight into the step's ``gt``
+        sharding while step i runs (after its dispatch, before its block);
+        ``train.batches_ahead`` counts those draws, ``steps - 1`` per call.
+        No batch past ``steps`` is drawn, so the view sequence is the
+        data's own whatever the calls' sizes."""
         m = self.obs.metrics
         loss_gauge = m.gauge("train.loss")
         step_ms = m.histogram("train.step_ms")
         device_ms = m.histogram("train.device_ms")
         gather_bytes = m.counter("train.gather_bytes")
         steps_total = m.counter("train.steps")
+        ahead = m.counter("train.batches_ahead")
         rid = new_request_id()  # one span tree per fit call
         gb = all_gather_bytes_per_step(self.cfg, self.mesh, self.state.params.n)
         losses = []
         t0 = now()
+        place = gt_sharding(self.mesh, self.cfg)
         batches = iter(data.batches(self.cfg.batch_size, steps=steps))
+        got = draw_batch(batches, place, self.obs.trace, rid, 0) if steps > 0 else None
         for i in range(steps):
-            rec = self.obs.trace
-            with rec.span(rid, "batch", step=i) if rec else NO_SPAN as sp:
-                got = next(batches, None)
-                if got is None:
-                    sp.drop()  # the stream ended early: no batch was assembled
             if got is None:
-                break
+                break  # the stream ended early
             cams, gt = got
+            rec = self.obs.trace
             t_batch = now()
             with rec.span(rid, "dispatch", step=i) if rec else NO_SPAN:
                 self.state, metrics = self.step_fn(self.state, cams, gt)
+            t_disp = now()
+            got = draw_batch(batches, place, rec, rid, i + 1) if i + 1 < steps else None
+            if got is not None:
+                ahead.inc()
             if rec:
-                t_disp = now()
                 jax.block_until_ready(self.state)
                 t_dev = now()
                 rec.record(rid, "device", t_disp, t_dev, step=i)
